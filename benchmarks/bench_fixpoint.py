@@ -797,7 +797,9 @@ def run(n_v=5_000, n_e=200_000, width_fracs=(0.005, 0.02), W=8, advances=6,
     if "sharded" in parts:
         s_nv, s_ne, s_frac, s_sdiv, s_q, s_headway = (
             20_000, 60_000, 0.08, 64, 16, 300)
-        shard_env = dict(os.environ)
+        # the children measure forced CPU host devices, never the
+        # accelerator this process may hold
+        shard_env = dict(os.environ, JAX_PLATFORMS="cpu")
         rows4, ratio1 = [], None
         for D in dev_counts:
             out = subprocess.run(
@@ -1026,7 +1028,8 @@ def run(n_v=5_000, n_e=200_000, width_fracs=(0.005, 0.02), W=8, advances=6,
                      str(mesh2d_cands), str(rg["q"]), str(rg["ndeep"]),
                      str(rg["ndup"]), rg["order"],
                      _REPO_ROOT, str(rg["headway"])],
-                    capture_output=True, text=True, env=dict(os.environ),
+                    capture_output=True, text=True,
+                    env=dict(os.environ, JAX_PLATFORMS="cpu"),
                     cwd=_REPO_ROOT, timeout=2400,
                 )
                 assert out.returncode == 0, (

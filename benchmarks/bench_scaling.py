@@ -52,8 +52,9 @@ _PROG = textwrap.dedent(
 
 def run(dev_counts=(1, 2, 4, 8)):
     base = None
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    # the children measure forced CPU host devices, never the accelerator
+    # the parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     for n in dev_counts:
         out = subprocess.run(
             [sys.executable, "-c", _PROG % (n, n)],
@@ -61,8 +62,9 @@ def run(dev_counts=(1, 2, 4, 8)):
             cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         )
         if out.returncode != 0:
-            emit(f"fig7/ea_round/dev{n}", 0.0, f"FAILED:{out.stderr[-200:]}")
-            continue
+            raise RuntimeError(
+                f"fig7 ea_round child on {n} host devices failed:\n"
+                f"{out.stderr[-3000:]}")
         sec = json.loads(out.stdout.strip().splitlines()[-1])["sec_per_round"]
         base = base or sec
         emit(f"fig7/ea_round/dev{n}", sec, f"speedup_vs_1dev={base/sec:.2f}x")

@@ -26,6 +26,8 @@ def main() -> None:
                          "frontier-rung ladder deep/crossover rows)")
     args = ap.parse_args()
     wanted = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     def want(name):
         return wanted is None or name in wanted

@@ -11,8 +11,8 @@ window narrows.
 """
 import numpy as np
 
-from repro.core import build_tger, decision_for
-from repro.engine import make_plan
+from repro.core import build_tger
+from repro.engine import decision_for, make_plan
 from repro.core.algorithms import earliest_arrival, temporal_betweenness
 from repro.core.selective import CostModel
 from repro.data.generators import power_law_temporal_graph

@@ -4,7 +4,8 @@
 """
 import numpy as np
 
-from repro.core import build_tger, decision_for
+from repro.core import build_tger
+from repro.engine import decision_for
 from repro.core.algorithms import (
     earliest_arrival,
     temporal_cc,

@@ -15,8 +15,7 @@ PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q "$@"
 # forced-multi-device leg: the query-mesh sharding paths (DESIGN.md §7.5)
 # only exercise real device boundaries when XLA fakes >1 host device, so
 # rerun the distributed + sharded-serving suites under a 4-device CPU
-# backend.  The workflow matrix runs this script under both the jax 0.4.37
-# floor and jax-latest, so the shard_map compat shims get both pins.
+# backend.
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m pytest -x -q tests/test_distributed.py tests/test_sharded_serving.py
@@ -24,8 +23,7 @@ XLA_FLAGS="--xla_force_host_platform_device_count=4" \
 # forced-8-device leg: the edge×query 2-D meshes (DESIGN.md §7.7) at
 # mesh shapes (2,4) and (4,2) — both axes genuinely multi-device, which
 # the 4-device leg above (max (2,2)) cannot produce.  Reuses the
-# env-parameterized 2-D soak with CI-reduced advances; runs on both jax
-# matrix legs like the rest of this script.
+# env-parameterized 2-D soak with CI-reduced advances.
 SOAK2D_DEVICES=8 SOAK2D_MESHES="2x4,4x2" SOAK2D_STEPS=12 \
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m pytest -x -q tests/test_sharded_serving.py -k soak_2d
@@ -35,7 +33,7 @@ SOAK2D_DEVICES=8 SOAK2D_MESHES="2x4,4x2" SOAK2D_STEPS=12 \
 # soak at the full 48 advances — one fused dispatch per advance, zero
 # retraces after warmup, rows bit-identical to the compaction-off chain
 # on EVERY advance, cold-store watermark tracking the ring's low
-# watermark.  Runs on both legs of the jax version matrix.
+# watermark.
 COLD_SOAK=48 \
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m pytest -x -q tests/test_coldstore.py -k compaction_soak
@@ -43,8 +41,7 @@ COLD_SOAK=48 \
 # smoke the serving daemon end to end (DESIGN.md §7.6): a short tick loop
 # with Poisson tenant churn, bucketed async admission and cost-class
 # round-robin — the launch-path wiring the daemon soak in tier-1 above
-# (tests/test_daemon.py, CI-reduced) does not cover.  Runs on both legs
-# of the jax version matrix like everything else in this script.
+# (tests/test_daemon.py, CI-reduced) does not cover.
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
   python -m repro.launch.serve --graph --daemon --ticks 8 --tenants 8 \
   --n-vertices 500 --n-edges 10000
@@ -83,6 +80,5 @@ rm -rf "$SPILL_DIR"
 # BEFORE timing, zero retraces on repeated same-shape laddered solves
 # asserted from the trace log) and the honest shallow power-law
 # crossover row, plus the part-2b tiny-budget gate assertion inside the
-# fixpoint leg above — merges part 8 into BENCH_fixpoint.json.  Runs on
-# both legs of the jax version matrix like everything else here.
+# fixpoint leg above — merges part 8 into BENCH_fixpoint.json.
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.run --quick --only frontier

@@ -28,20 +28,23 @@ def _follows(pred, src_end, ts):
 
 
 def earliest_arrival_ref(g, source, window, pred="succeeds"):
+    """Round-synchronous relaxation to the fixpoint: every round relaxes
+    all window edges whose source arrival precedes them (``np.minimum.at``
+    is the per-destination min), until no arrival improves."""
     src, dst, ts, te, _ = _edges(g)
     ta, tb = window
     ok = (ts >= ta) & (te <= tb)
+    src, dst, ts, te = src[ok], dst[ok], ts[ok], te[ok]
     arr = np.full(g.n_vertices, INT_INF, np.int64)
     arr[source] = ta
     for _ in range(g.n_vertices + 1):
-        relax = ok & (arr[src] < INT_INF) & _follows(pred, arr[src], ts)
-        changed = False
-        for e in np.nonzero(relax)[0]:
-            if te[e] < arr[dst[e]]:
-                arr[dst[e]] = te[e]
-                changed = True
-        if not changed:
+        a = arr[src]
+        relax = (a < INT_INF) & _follows(pred, a, ts)
+        new = arr.copy()
+        np.minimum.at(new, dst[relax], te[relax])
+        if (new == arr).all():
             break
+        arr = new
     return arr
 
 
@@ -157,22 +160,22 @@ def temporal_bfs_ref(g, source, window, pred="succeeds"):
 
 
 def temporal_cc_ref(g, window):
+    """Min-label propagation over the window's edges, undirected: every
+    vertex ends labelled with the smallest vertex id of its component."""
     src, dst, ts, te, _ = _edges(g)
     ta, tb = window
     ok = (ts >= ta) & (te <= tb)
+    src, dst = src[ok], dst[ok]
     labels = np.arange(g.n_vertices)
     for _ in range(g.n_vertices + 1):
-        changed = False
-        for e in np.nonzero(ok)[0]:
-            a, b = labels[src[e]], labels[dst[e]]
-            m = min(a, b)
-            if labels[src[e]] != m or labels[dst[e]] != m:
-                # union by min-label (propagate to roots)
-                labels[labels == a] = m
-                labels[labels == b] = m
-                changed = True
-        if not changed:
+        m = np.minimum(labels[src], labels[dst])
+        new = labels.copy()
+        np.minimum.at(new, src, m)
+        np.minimum.at(new, dst, m)
+        new = new[new]  # pointer jump: a label is a vertex of the component
+        if (new == labels).all():
             break
+        labels = new
     return labels
 
 

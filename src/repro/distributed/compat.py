@@ -1,49 +1,26 @@
-"""JAX version compatibility for the distributed runtime.
+"""The two mesh primitives the distributed runtime builds on, spelled once.
 
-The codebase targets current jax (``jax.shard_map`` with ``check_vma``,
-``jax.sharding.AxisType`` meshes); CI and the dev container may carry an
-older release (0.4.x: ``jax.experimental.shard_map`` with ``check_rep``,
-``make_mesh`` without ``axis_types``).  These two wrappers pick whichever
-spelling exists so the engine runs unchanged on both.
+``shard_map`` maps the engine's ``check`` flag onto ``jax.shard_map``'s
+``check_vma``; ``make_mesh`` builds meshes with Auto axis types, the
+sharding mode every mesh in this repo is written for.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 
 
-def shard_map(f=None, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions (``check`` maps onto
-    ``check_vma`` on new jax, ``check_rep`` on old).  Usable directly or as
-    a decorator via ``functools.partial``-style keyword-only invocation."""
-    if f is None:
-        return functools.partial(
-            shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check=check,
-        )
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
+def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
+    """``jax.shard_map`` with replication checking off unless asked for;
+    usable as a decorator through ``functools.partial``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 __all__ = ["shard_map", "make_mesh"]

@@ -7,7 +7,7 @@ scale that dispatch ACROSS devices by partitioning the batch's expanded
 
   * :func:`query_mesh` — the mesh itself, built on the mesh axis the
     ``"queries"`` logical rule reserves (``distributed/sharding.py``:
-    ``queries -> "model"``), via the version-portable ``compat.make_mesh``.
+    ``queries -> "model"``), via ``compat.make_mesh``.
   * :func:`row_partition` — the pad-and-mask row layout: ``n_rows`` rows
     partition into ``n_shards`` CONTIGUOUS chunks of ``cap = ceil(n/D)``
     rows; the tail pads by REPEATING THE LAST REAL ROW (a real solve whose

@@ -6,19 +6,22 @@ Two implementations of the one protocol:
     these to scatter; fine on CPU/GPU, serializing on TPU);
   * ``pallas_tiled`` — destination-tile fused kernels
     (kernels/layout.py + kernels/temporal_edgemap.py + kernels/segment_spmm.py):
-    the scatter becomes a VMEM-local compare-select tree (min) or a one-hot
-    MXU matmul (sum).  Runs in interpret mode on CPU; ``interpret=False``
-    on TPU.
+    the scatter becomes a VMEM-local compare-select sweep (min), the same
+    sweep adding (one-feature sum) or a one-hot MXU matmul (feature-matrix
+    sum).  Interpret mode follows the platform
+    (``repro.kernels.resolve_interpret``).
 
 The pallas backend accelerates what the tile layout covers — int32 min and
 f32 sum combines over the graph's native destination order (scan method,
-out direction).  Everything else transparently falls back to xla_segment,
-so a plan's backend choice is a performance hint, never a correctness
-constraint.
+out direction).  Everything else falls back to xla_segment, so a plan's
+backend choice is a performance hint, never a correctness constraint —
+but never a silent one: every combine a ``pallas_tiled`` plan sends to the
+segment path is counted by reason in :func:`pallas_fallbacks` (once per
+trace of the enclosing program, since the choice is made while tracing).
 """
 from __future__ import annotations
 
-from typing import Optional, Protocol
+from typing import Dict, Optional, Protocol
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +29,20 @@ import jax.numpy as jnp
 from repro.engine.plan import AccessPlan
 
 INT_INF = jnp.iinfo(jnp.int32).max
+
+_PALLAS_FALLBACKS: Dict[str, int] = {}
+
+
+def pallas_fallbacks() -> Dict[str, int]:
+    """Combines a ``pallas_tiled`` plan sent to the segment path, by reason
+    (``ineligible``: dtype, rank or edge count the layout does not cover;
+    ``not_layout_order``: segment ids not in the layout's edge order;
+    ``edge_sharded``: an edge-sharded mesh body).  Counted at trace time."""
+    return dict(_PALLAS_FALLBACKS)
+
+
+def _fell_back(reason: str) -> None:
+    _PALLAS_FALLBACKS[reason] = _PALLAS_FALLBACKS.get(reason, 0) + 1
 
 
 def _identity(combine: str, dtype) -> jax.Array:
@@ -134,9 +151,6 @@ class PallasTiledBackend:
 
     name = "pallas_tiled"
 
-    def __init__(self, interpret: bool = True):
-        self.interpret = interpret
-
     # -- eligibility (static, trace-time) -----------------------------------
     def _supports(self, plan, values, num_segments, op) -> bool:
         if plan is None or plan.layout_perm.shape[0] == 0:
@@ -163,6 +177,7 @@ class PallasTiledBackend:
 
     def combine(self, plan, values, segment_ids, num_segments, op, mask=None):
         if not self._supports(plan, values, num_segments, op):
+            _fell_back("ineligible")
             return segment_combine(values, segment_ids, num_segments, op, mask=mask)
         if op == "min":
             return self._combine_min(plan, values, segment_ids, num_segments, mask)
@@ -174,6 +189,7 @@ class PallasTiledBackend:
         then the tiled kernel runs per window under ``lax.map`` (one trace,
         W sequential kernel launches — the kernel itself is not re-batched)."""
         if not self._supports(plan, values[0], num_segments, op):
+            _fell_back("ineligible")
             return segment_combine_windows(values, segment_ids, num_segments,
                                            op, masks=masks)
         if op == "min":
@@ -190,27 +206,26 @@ class PallasTiledBackend:
         cand_g = jnp.where(in_perm, cand[safe], INT_INF)
         tiles = segment_min_tiles(
             dst_local, cand_g, plan.layout_block_tile, plan.n_tiles,
-            tile_v=plan.tile_v, block_e=plan.block_e,
-            interpret=self.interpret,
-        )
+            tile_v=plan.tile_v, block_e=plan.block_e)
         return tiles.reshape(-1)[:num_segments]
 
     def _combine_sum(self, plan, values, segment_ids, num_segments, mask):
         from repro.kernels.segment_spmm import segment_spmm_tiles
+        from repro.kernels.temporal_edgemap import segment_sum_tiles
 
-        squeeze = values.ndim == 1
-        msgs = values[:, None] if squeeze else values
         safe, in_perm, dst_local = self._gathered(plan, segment_ids)
-        msg_g = msgs[safe]
         valid = in_perm if mask is None else in_perm & mask[safe]
+        if values.ndim == 1:  # one feature: lane-packed, no feature padding
+            msg_g = jnp.where(valid, values[safe], 0)
+            tiles = segment_sum_tiles(
+                dst_local, msg_g, plan.layout_block_tile, plan.n_tiles,
+                tile_v=plan.tile_v, block_e=plan.block_e)
+            return tiles.reshape(-1)[:num_segments]
         tiles = segment_spmm_tiles(
-            dst_local, msg_g, valid.astype(jnp.int32),
+            dst_local, values[safe], valid.astype(jnp.int32),
             plan.layout_block_tile, plan.n_tiles,
-            tile_v=plan.tile_v, block_e=plan.block_e,
-            interpret=self.interpret,
-        )
-        out = tiles.reshape(-1, msgs.shape[-1])[:num_segments]
-        return out[:, 0] if squeeze else out
+            tile_v=plan.tile_v, block_e=plan.block_e)
+        return tiles.reshape(-1, values.shape[-1])[:num_segments]
 
     # -- batched-window variants (shared layout gather, per-window kernel) ---
     def _combine_min_windows(self, plan, values, segment_ids, num_segments,
@@ -224,9 +239,7 @@ class PallasTiledBackend:
         def one(c):
             tiles = segment_min_tiles(
                 dst_local, c, plan.layout_block_tile, plan.n_tiles,
-                tile_v=plan.tile_v, block_e=plan.block_e,
-                interpret=self.interpret,
-            )
+                tile_v=plan.tile_v, block_e=plan.block_e)
             return tiles.reshape(-1)[:num_segments]
 
         return jax.lax.map(one, cand_g)
@@ -234,33 +247,38 @@ class PallasTiledBackend:
     def _combine_sum_windows(self, plan, values, segment_ids, num_segments,
                              masks):
         from repro.kernels.segment_spmm import segment_spmm_tiles
+        from repro.kernels.temporal_edgemap import segment_sum_tiles
 
-        squeeze = values.ndim == 2
-        msgs = values[..., None] if squeeze else values      # [W, K, F]
         safe, in_perm, dst_local = self._gathered(plan, segment_ids)
-        msg_g = msgs[:, safe, :]                             # [W, Ep, F]
         if masks is None:
-            valid = jnp.broadcast_to(in_perm, (msgs.shape[0], in_perm.shape[0]))
+            valid = jnp.broadcast_to(in_perm, (values.shape[0], in_perm.shape[0]))
         else:
             valid = in_perm[None, :] & masks[:, safe]
+        if values.ndim == 2:  # [W, K]: one feature per window, lane-packed
+            msg_g = jnp.where(valid, values[:, safe], 0)
+
+            def one(m):
+                tiles = segment_sum_tiles(
+                    dst_local, m, plan.layout_block_tile, plan.n_tiles,
+                    tile_v=plan.tile_v, block_e=plan.block_e)
+                return tiles.reshape(-1)[:num_segments]
+
+            return jax.lax.map(one, msg_g)
 
         def one(args):
             m, v = args
             tiles = segment_spmm_tiles(
                 dst_local, m, v.astype(jnp.int32),
                 plan.layout_block_tile, plan.n_tiles,
-                tile_v=plan.tile_v, block_e=plan.block_e,
-                interpret=self.interpret,
-            )
+                tile_v=plan.tile_v, block_e=plan.block_e)
             return tiles.reshape(-1, m.shape[-1])[:num_segments]
 
-        out = jax.lax.map(one, (msg_g, valid))
-        return out[..., 0] if squeeze else out
+        return jax.lax.map(one, (values[:, safe, :], valid))
 
 
 _BACKENDS = {
     "xla_segment": XlaSegmentBackend(),
-    "pallas_tiled": PallasTiledBackend(interpret=True),
+    "pallas_tiled": PallasTiledBackend(),
 }
 
 
@@ -269,6 +287,20 @@ def get_backend(name: str) -> ExecutionBackend:
         return _BACKENDS[name]
     except KeyError:
         raise ValueError(f"unknown backend {name!r}; have {sorted(_BACKENDS)}")
+
+
+def _takes_layout(plan, use_layout: bool, axis) -> bool:
+    """Whether a plan-directed combine runs the tiled kernels; a
+    ``pallas_tiled`` plan that cannot is counted as a fallback."""
+    if plan is None or plan.backend != "pallas_tiled":
+        return False
+    if axis is not None:
+        _fell_back("edge_sharded")
+        return False
+    if not use_layout:
+        _fell_back("not_layout_order")
+        return False
+    return True
 
 
 def combine_for_plan(
@@ -290,8 +322,7 @@ def combine_for_plan(
     grouping that does not partition along the ring shards — and finishes
     with the one cross-shard collective."""
     axis = None if plan is None else plan.edge_axis
-    if (plan is not None and use_layout and axis is None
-            and plan.backend == "pallas_tiled"):
+    if _takes_layout(plan, use_layout, axis):
         return get_backend("pallas_tiled").combine(
             plan, values, segment_ids, num_segments, op, mask=mask
         )
@@ -314,8 +345,7 @@ def combine_windows_for_plan(
     Same layout-eligibility (and ``edge_axis``) contract as
     :func:`combine_for_plan`."""
     axis = None if plan is None else plan.edge_axis
-    if (plan is not None and use_layout and axis is None
-            and plan.backend == "pallas_tiled"):
+    if _takes_layout(plan, use_layout, axis):
         return get_backend("pallas_tiled").combine_windows(
             plan, values, segment_ids, num_segments, op, masks=masks
         )
@@ -330,6 +360,7 @@ __all__ = [
     "segment_combine",
     "segment_combine_windows",
     "get_backend",
+    "pallas_fallbacks",
     "combine_for_plan",
     "combine_windows_for_plan",
 ]

@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -86,7 +89,7 @@ def decode_attention_pallas(
     cache_len,     # i32[B]
     *,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns o [B, H, Dh] = softmax(q k^T / sqrt(Dh)) v over valid cache."""
     B, H, Dh = q.shape
@@ -123,7 +126,7 @@ def decode_attention_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Dh), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
         jnp.asarray(cache_len, jnp.int32), qr,
         k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),

@@ -2,8 +2,8 @@
 
 ``relax_min`` / ``spmm`` take plain edge arrays, apply the destination-tile
 layout (built once per graph and cached by callers), invoke the kernel, and
-unpack tiles back to a dense [V] / [V, D] result.  On CPU (this container)
-the kernels run in interpret mode; on TPU set ``interpret=False``.
+unpack tiles back to a dense [V] / [V, D] result.  ``interpret=None``
+leaves interpret mode to the platform (``repro.kernels.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ def relax_min(
     window,
     *,
     strict: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused temporal relax via the Pallas kernel: returns cand[V] minima."""
     perm = jnp.asarray(layout.perm)
@@ -71,7 +71,7 @@ def earliest_arrival_kernel(
     *,
     strict: bool = False,
     max_rounds: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Earliest arrival executed through the Pallas relax kernel — the
     kernel as an engine backend rather than a standalone op.  Host fixpoint
@@ -103,7 +103,7 @@ def spmm(
     valid_edges=None,
     tile_v: int = 256,
     block_e: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Segment-sum of messages by destination via the Pallas kernel."""
     perm = jnp.asarray(layout.perm)

@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.models import transformer as tf
 from repro.serve.engine import GraphBatchServer, Request, ServeEngine
@@ -230,6 +231,7 @@ def main():
     ap.add_argument("--depart-rate", type=float, default=0.25,
                     help="Poisson tenant departures per tick")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.history_spill_dir and not args.history_chunks:
         ap.error("--history-spill-dir needs --history-chunks (it spills "

@@ -202,3 +202,59 @@ def test_batched_pagerank_and_reachability_vs_oracle():
     for i, w in enumerate(wins):
         ref = R.overlaps_reachability_ref(g, src, (int(w[0]), int(w[1])))
         assert (np.asarray(reach)[i] <= ref).all()
+
+
+def _earliest_arrival_loop(g, source, window):
+    """The per-edge loop oracle the vectorised ``earliest_arrival_ref``
+    replaced (in-place relaxation inside a round)."""
+    src, dst, ts, te = (np.asarray(a) for a in (g.src, g.dst, g.t_start,
+                                                 g.t_end))
+    ok = (ts >= window[0]) & (te <= window[1])
+    arr = np.full(g.n_vertices, R.INT_INF, np.int64)
+    arr[source] = window[0]
+    for _ in range(g.n_vertices + 1):
+        relax = ok & (arr[src] < R.INT_INF) & (arr[src] <= ts)
+        changed = False
+        for e in np.nonzero(relax)[0]:
+            if te[e] < arr[dst[e]]:
+                arr[dst[e]] = te[e]
+                changed = True
+        if not changed:
+            break
+    return arr
+
+
+def _cc_loop(g, window):
+    """The per-edge union-by-min-label loop oracle the vectorised
+    ``temporal_cc_ref`` replaced."""
+    src, dst, ts, te = (np.asarray(a) for a in (g.src, g.dst, g.t_start,
+                                                 g.t_end))
+    ok = (ts >= window[0]) & (te <= window[1])
+    labels = np.arange(g.n_vertices)
+    for _ in range(g.n_vertices + 1):
+        changed = False
+        for e in np.nonzero(ok)[0]:
+            a, b = labels[src[e]], labels[dst[e]]
+            m = min(a, b)
+            if a != m or b != m:
+                labels[labels == a] = m
+                labels[labels == b] = m
+                changed = True
+        if not changed:
+            break
+    return labels
+
+
+@pytest.mark.parametrize("seed", [3, 11, 29])
+@pytest.mark.parametrize("oracle", ["earliest_arrival", "cc"])
+def test_vectorised_oracles_match_loop_oracles(oracle, seed):
+    """Same integer arithmetic, another order: results must be equal."""
+    g = synthetic_temporal_graph(60, 400, seed=seed)
+    ts = np.asarray(g.t_start)
+    win = (int(np.quantile(ts, 0.2)), int(np.asarray(g.t_end).max()))
+    if oracle == "cc":
+        assert np.array_equal(R.temporal_cc_ref(g, win), _cc_loop(g, win))
+    else:
+        for src in range(0, 60, 7):
+            assert np.array_equal(R.earliest_arrival_ref(g, src, win),
+                                  _earliest_arrival_loop(g, src, win))

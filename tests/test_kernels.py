@@ -50,6 +50,36 @@ def test_relax_min_strict():
     assert (np.asarray(got) == np.asarray(ref)).all()
 
 
+@pytest.mark.parametrize("n_v,n_e,tile_v,block_e", [
+    (100, 700, 64, 128),
+    (700, 6000, 256, 512),
+    (513, 2000, 128, 256),     # non-multiple vertex count
+    (64, 64, 64, 128),         # fewer edges than one block
+])
+def test_segment_sum_tiles_sweep(n_v, n_e, tile_v, block_e):
+    """One-feature sums in the lane-packed layout == the segment oracle."""
+    from repro.kernels.temporal_edgemap import segment_sum_tiles
+
+    g = synthetic_temporal_graph(n_v, n_e, seed=n_e + 1)
+    layout = ops.prepare_layout(np.asarray(g.dst), n_v, tile_v=tile_v,
+                                block_e=block_e)
+    rng = np.random.default_rng(n_e)
+    msgs = rng.standard_normal(n_e).astype(np.float32)
+    valid = rng.random(n_e) < 0.7
+    perm = np.asarray(layout.perm)
+    safe = np.maximum(perm, 0)
+    dst_g = np.asarray(g.dst)[safe]
+    msg_g = np.where((perm >= 0) & valid[safe], msgs[safe], 0).astype(np.float32)
+    tiles = segment_sum_tiles(
+        jnp.asarray(dst_g % tile_v, jnp.int32), jnp.asarray(msg_g),
+        jnp.asarray(layout.block_tile), -(-n_v // tile_v),
+        tile_v=tile_v, block_e=block_e)
+    got = np.asarray(tiles).reshape(-1)[:n_v]
+    ref = segment_spmm_ref(g.dst, jnp.asarray(msgs)[:, None],
+                           jnp.asarray(valid), n_v)
+    np.testing.assert_allclose(got, np.asarray(ref)[:, 0], rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("d", [16, 48, 128, 130])
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_spmm_sweep(d, dtype):
