@@ -133,6 +133,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, time_fn
+from repro import telemetry
 from repro.core.algorithms import earliest_arrival
 from repro.core.edgemap import INT_INF, frontier_from_sources, temporal_edge_map
 from repro.core.predicates import OrderingPredicateType, edge_follows
@@ -955,17 +956,16 @@ def run(n_v=5_000, n_e=200_000, width_fracs=(0.005, 0.02), W=8, advances=6,
         for _ in range(10):                  # resident base population
             live.append(server.submit(fresh_spec()))
         warm_ticks = min(5, daemon_ticks // 2)
-        skip = 0
         for k in range(daemon_ticks):
             server.tick(base5 + k * stride)
-            if k == warm_ticks - 1:
-                skip = len(server.latencies)
             for _ in range(rng5.poisson(0.4)):
                 live.append(server.submit(fresh_spec()))
             for _ in range(rng5.poisson(0.2)):
                 if len(live) > 2:
                     server.retire(live.pop(int(rng5.integers(len(live)))))
-        lat = np.asarray(server.latencies[skip:]) * 1e6
+        lat = np.asarray([
+            d for r in telemetry.recent(daemon_ticks - warm_ticks)
+            for d in r.durations("serve.class")]) * 1e6
         p50 = float(np.percentile(lat, 50))
         p99 = float(np.percentile(lat, 99))
         s5 = server.stats

@@ -233,6 +233,7 @@ def serve(dep: Deployment, meter: CompileMeter) -> None:
 
 
 def daemon(dep: Deployment, seed: int) -> None:
+    from repro import telemetry
     from repro.engine import QuerySpec
     from repro.serve.engine import GraphBatchServer
 
@@ -263,7 +264,8 @@ def daemon(dep: Deployment, seed: int) -> None:
         n_spawned += 1
         server.retire(live.pop(int(rng.integers(len(live)))))
     s = server.stats
-    lat = np.asarray(server.latencies)
+    lat = np.asarray([d for r in telemetry.recent(s.ticks)
+                      for d in r.durations("serve.class")])
     say("daemon", ticks=s.ticks, class_advances=s.advances,
         cold=s.cold_advances, fused=s.fused_dispatches,
         admissions=s.admissions, retirements=s.retirements,

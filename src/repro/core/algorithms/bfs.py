@@ -94,6 +94,7 @@ def temporal_bfs(
 @functools.partial(
     jax.jit, static_argnames=("n_vertices", "pred", "max_rounds")
 )
+@jax.named_scope("fixpoint.bfs")
 def _temporal_bfs_over_view_dense(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

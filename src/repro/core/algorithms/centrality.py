@@ -100,6 +100,7 @@ def _brandes_row(edges, valid_row, window, source, t, P: int,
     jax.jit,
     static_argnames=("n_vertices", "pred", "n_buckets"),
 )
+@jax.named_scope("fixpoint.betweenness")
 def _brandes_from_t(edges, windows, sources, valid, t, *, plan,
                     n_vertices: int, pred: OrderingPredicateType,
                     n_buckets: int):

@@ -82,6 +82,7 @@ def temporal_cc(
 
 
 @functools.partial(jax.jit, static_argnames=("n_vertices", "max_rounds"))
+@jax.named_scope("fixpoint.cc")
 def _temporal_cc_over_view_dense(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

@@ -73,6 +73,7 @@ def temporal_kcore(
 
 
 @functools.partial(jax.jit, static_argnames=("n_vertices", "max_rounds"))
+@jax.named_scope("fixpoint.kcore")
 def _temporal_kcore_over_view_dense(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

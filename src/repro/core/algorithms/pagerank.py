@@ -52,6 +52,7 @@ def temporal_pagerank(
 @functools.partial(
     jax.jit, static_argnames=("n_vertices", "n_iters")
 )
+@jax.named_scope("fixpoint.pagerank")
 def temporal_pagerank_over_view(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

@@ -142,6 +142,7 @@ def earliest_arrival_multi(g, sources, window, tger=None, **kw):
     static_argnames=("n_vertices", "pred", "max_rounds", "visit_once",
                      "with_rounds"),
 )
+@jax.named_scope("fixpoint.earliest_arrival")
 def _earliest_arrival_over_view_dense(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

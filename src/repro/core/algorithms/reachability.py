@@ -129,6 +129,7 @@ def overlaps_reachability(
 
 
 @functools.partial(jax.jit, static_argnames=("n_vertices", "max_rounds"))
+@jax.named_scope("fixpoint.reachability")
 def _overlaps_reachability_over_view_dense(
     edges: EdgeView,
     windows: jax.Array,             # i32[Q, 2]

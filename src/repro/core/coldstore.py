@@ -39,6 +39,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.temporal_graph import TemporalGraph
 from repro.core.tger import TGERIndex, window_positions_host
 
@@ -327,10 +328,11 @@ class ColdStore:
                 f"time-first positions but the plan's ring capacity is "
                 f"{capacity}; replan (the cold tier rungs its capacity "
                 f"from the window span)")
-        s = np.arange(capacity, dtype=np.int64)
-        pos = lo + (s - lo) % capacity
-        fields = self.gather_positions(pos)
-        return fields, pos < hi, lo, hi
+        with telemetry.span("cold.stitch"):
+            s = np.arange(capacity, dtype=np.int64)
+            pos = lo + (s - lo) % capacity
+            fields = self.gather_positions(pos)
+            return fields, pos < hi, lo, hi
 
     # -- stats ---------------------------------------------------------------
 

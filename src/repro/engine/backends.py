@@ -58,16 +58,11 @@ def _identity(combine: str, dtype) -> jax.Array:
     raise ValueError(combine)
 
 
-def segment_combine(values, segment_ids, num_segments: int, combine: str,
-                    mask=None, axis=None):
-    """Masked segment-reduce; invalid lanes contribute the identity.
+_COLLECTIVE = dict(min=jax.lax.pmin, max=jax.lax.pmax, sum=jax.lax.psum)
 
-    ``axis`` names a mesh axis the EDGE axis of ``values``/``segment_ids``
-    is sharded over (DESIGN.md §7.7): each device reduces its local edge
-    chunk into a full [num_segments] partial, then one ``pmin/pmax/psum``
-    over the axis combines the partials — min/max/sum are associative and
-    identity-padded, so the sharded result equals the unsharded one (sum
-    up to f32 reduction order).  ``axis=None`` is the plain local reduce."""
+
+def _masked_reduce(values, segment_ids, num_segments: int, combine: str,
+                   mask=None):
     ident = _identity(combine, values.dtype)
     if mask is not None:
         m = mask
@@ -81,11 +76,25 @@ def segment_combine(values, segment_ids, num_segments: int, combine: str,
     )[combine]
     # segment_min/max fill empty segments with the dtype's max/min (the
     # identity), segment_sum with 0 — identity semantics hold without fixup.
-    out = fn(values, segment_ids, num_segments=num_segments)
-    if axis is not None:
-        coll = dict(min=jax.lax.pmin, max=jax.lax.pmax, sum=jax.lax.psum)[combine]
-        out = coll(out, axis_name=axis)
-    return out
+    return fn(values, segment_ids, num_segments=num_segments)
+
+
+def segment_combine(values, segment_ids, num_segments: int, combine: str,
+                    mask=None, axis=None):
+    """Masked segment-reduce; invalid lanes contribute the identity.  Named
+    ``combine`` in the program.
+
+    ``axis`` names a mesh axis the EDGE axis of ``values``/``segment_ids``
+    is sharded over (DESIGN.md §7.7): each device reduces its local edge
+    chunk into a full [num_segments] partial, then one ``pmin/pmax/psum``
+    over the axis combines the partials — min/max/sum are associative and
+    identity-padded, so the sharded result equals the unsharded one (sum
+    up to f32 reduction order).  ``axis=None`` is the plain local reduce."""
+    with jax.named_scope("combine"):
+        out = _masked_reduce(values, segment_ids, num_segments, combine, mask)
+        if axis is not None:
+            out = _COLLECTIVE[combine](out, axis_name=axis)
+        return out
 
 
 def segment_combine_windows(values, segment_ids, num_segments: int,
@@ -95,20 +104,22 @@ def segment_combine_windows(values, segment_ids, num_segments: int,
     [W, K]; ``segment_ids`` [K] is shared across windows.  Returns
     [W, num_segments, ...] — W reductions over ONE gathered edge set.
     ``axis`` as in :func:`segment_combine`: one cross-edge-shard collective
-    per call, applied to the whole [W, num_segments] partial at once."""
-    if masks is None:
-        out = jax.vmap(
-            lambda v: segment_combine(v, segment_ids, num_segments, combine)
-        )(values)
-    else:
-        out = jax.vmap(
-            lambda v, m: segment_combine(v, segment_ids, num_segments, combine,
-                                         mask=m)
-        )(values, masks)
-    if axis is not None:
-        coll = dict(min=jax.lax.pmin, max=jax.lax.pmax, sum=jax.lax.psum)[combine]
-        out = coll(out, axis_name=axis)
-    return out
+    per call, applied to the whole [W, num_segments] partial at once.
+    Named ``combine`` in the program."""
+    with jax.named_scope("combine"):
+        if masks is None:
+            out = jax.vmap(
+                lambda v: _masked_reduce(v, segment_ids, num_segments,
+                                         combine)
+            )(values)
+        else:
+            out = jax.vmap(
+                lambda v, m: _masked_reduce(v, segment_ids, num_segments,
+                                            combine, mask=m)
+            )(values, masks)
+        if axis is not None:
+            out = _COLLECTIVE[combine](out, axis_name=axis)
+        return out
 
 
 class ExecutionBackend(Protocol):

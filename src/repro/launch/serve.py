@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.models import transformer as tf
@@ -170,7 +171,8 @@ def run_daemon(args) -> None:
     dt = time.perf_counter() - t0
 
     s = server.stats
-    lat = np.asarray(server.latencies)
+    lat = np.asarray([d for r in telemetry.recent(s.ticks)
+                      for d in r.durations("serve.class")])
     print(
         f"daemon: {s.ticks} ticks, {s.advances} class advances "
         f"({s.cold_advances} cold, {s.fused_dispatches} fused), "
@@ -184,7 +186,7 @@ def run_daemon(args) -> None:
             f"{st['watermark']}, compaction {st['compaction_ratio']:.2f}x"
         )
     print(
-        f"per-advance latency: p50 {1e3 * np.percentile(lat, 50):.2f} ms, "
+        f"class serve: p50 {1e3 * np.percentile(lat, 50):.2f} ms, "
         f"p99 {1e3 * np.percentile(lat, 99):.2f} ms "
         f"({len(server.tenants)} tenants live at exit)"
     )

@@ -29,7 +29,6 @@ never sits in the dispatch a cheap tenant's latency waits on.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -37,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.engine.queries import DEFAULT_COST_CLASS, QueryBatch, QuerySpec
 from repro.models.transformer import LMConfig, decode_step, init_cache, prefill
 
@@ -187,7 +187,7 @@ class TickReport:
     retired: Tuple[int, ...]
     results: Dict[int, Any]         # tenant id -> [n_rows, V] host rows
                                     # (tuple of arrays for multi-output)
-    latency_s: float
+    latency_s: float                # the tick's ``serve.tick`` span
 
 
 class GraphBatchServer:
@@ -259,7 +259,6 @@ class GraphBatchServer:
         self.coldstore = coldstore
         self.state = None
         self.stats = GraphServeStats()
-        self.latencies: List[float] = []    # per class-serve seconds
         # -- daemon registries (tick mode) ---------------------------------
         self._tenants: Dict[int, QuerySpec] = {}    # tid -> template spec
         self._pending_admit: Deque[Tuple[int, QuerySpec]] = deque()
@@ -276,9 +275,12 @@ class GraphBatchServer:
 
     # -- batch mode ---------------------------------------------------------
 
+    @telemetry.request("serve.advance")
     def advance(self, batch) -> List:
         """Serve one batch tick; returns host-snapshot per-group results
-        (same grouping as :func:`repro.serve.serve_batch`)."""
+        (same grouping as :func:`repro.serve.serve_batch`).  One request of
+        :mod:`repro.telemetry`: each result's wait for the device
+        (``serve.wait``) is timed apart from its copy (``serve.copy``)."""
         from repro.serve import window_sweep as ws
 
         with ws.dispatch_log() as log:
@@ -295,11 +297,15 @@ class GraphBatchServer:
                 # let the retry run cold rather than reuse donated buffers
                 self.state = None
                 raise
-        snapped = [
-            tuple(np.asarray(x) for x in r) if isinstance(r, tuple)
-            else np.asarray(r)
-            for r in results
-        ]
+        snapped = []
+        for r in results:
+            # in group order, so a cold serve's copy of one group overlaps
+            # the next group's solve
+            with telemetry.span("serve.wait"):
+                jax.block_until_ready(r)
+            with telemetry.span("serve.copy"):
+                snapped.append(tuple(np.asarray(x) for x in r)
+                               if isinstance(r, tuple) else np.asarray(r))
         self.stats.advances += 1
         if self.state.last_advance == "cold":
             self.stats.cold_advances += 1
@@ -372,11 +378,11 @@ class GraphBatchServer:
         jitter group capacities across bucket rungs."""
         return self._admit_hr.get(cls, 0)
 
+    @telemetry.span("serve.class")
     def _serve_class(self, cls: str, sub: QueryBatch, tids: List[int],
                      results: Dict[int, Any]) -> None:
         from repro.serve import window_sweep as ws
 
-        t0 = time.perf_counter()
         # the history class serves pinned windows through the cold tier,
         # which refuses bucketed admission and the query mesh (its results
         # never ride the donated fused chain) — the hot classes keep both,
@@ -419,7 +425,6 @@ class GraphBatchServer:
                 picked = tuple(h[row_ids] for h in host)
                 results[tids[si]] = (
                     picked[0] if len(picked) == 1 else picked)
-        self.latencies.append(time.perf_counter() - t0)
 
     def tick(self, t_now: int) -> TickReport:
         """One daemon tick: apply pending churn, re-anchor every live
@@ -427,87 +432,88 @@ class GraphBatchServer:
         batch by cost class (cheap every tick, deep classes round-robin
         one per tick).  Returns a :class:`TickReport`; served tenants'
         results are host snapshots sliced to their real rows."""
-        t_start = time.perf_counter()
-        admitted: List[int] = []
-        arrived: Dict[str, int] = {}    # cost class -> rows admitted NOW
-        while self._pending_admit:
-            tid, spec = self._pending_admit.popleft()
-            self._tenants[tid] = spec
-            admitted.append(tid)
-            self.stats.admissions += 1
-            cls = self._class_of(spec)
-            arrived[cls] = arrived.get(cls, 0) + max(1, len(spec.sources))
-        retired: List[int] = []
-        while self._pending_retire:
-            tid = self._pending_retire.popleft()
-            if self._tenants.pop(tid, None) is not None:
-                retired.append(tid)
-                self.stats.retirements += 1
-        # stale-forecast flush (the empty-class bug): a class whose last
-        # tenant retired must NOT keep its EWMA/headroom entries — a
-        # re-admission after a quiet gap would inherit the old sticky
-        # headroom and oversize its first bucket.  Classes arriving THIS
-        # tick keep theirs (retire-and-replace churn is the learned rate).
-        live_now = {self._class_of(s) for s in self._tenants.values()}
-        for cls in list(self._admit_ewma):
-            if cls not in live_now and cls not in arrived:
-                self._admit_ewma.pop(cls, None)
-                self._admit_hr.pop(cls, None)
-        # arrival-rate EWMA (rows/tick) per cost class: decays every tick,
-        # spikes on bursts — bucket_headroom() reads it so the class's
-        # buckets are already sized when the NEXT burst lands
-        for cls in set(self._admit_ewma) | set(arrived):
-            prev = self._admit_ewma.get(cls, 0.0)
-            self._admit_ewma[cls] = (
-                (1.0 - self.EWMA_ALPHA) * prev
-                + self.EWMA_ALPHA * arrived.get(cls, 0))
-            # sticky headroom: grow on a higher forecast NOW (the next
-            # burst is what the headroom exists for), shrink only when
-            # the forecast collapses 4x (the ladder's hysteresis rule) —
-            # a raw ceil of the decaying EWMA would flap capacities
-            want = int(np.ceil(self._admit_ewma[cls]
-                               * self.HEADROOM_SAFETY))
-            held = self._admit_hr.get(cls, 0)
-            if want > held or want < held // 4:
-                self._admit_hr[cls] = want
-        self.stats.ticks += 1
-        tick_no = self.stats.ticks
-        results: Dict[int, Any] = {}
-        classes_served: Tuple[str, ...] = ()
-        if self._tenants:
-            # the instantaneous batch: every live tenant's window slid to
-            # end at t_now (width preserved from the submitted template) —
-            # EXCEPT pinned tenants, whose historical window is the whole
-            # point: it serves verbatim through the cold tier every tick
-            tids_all: List[int] = []
-            specs: List[QuerySpec] = []
-            for tid, spec in self._tenants.items():
-                if spec.pinned:
-                    specs.append(spec)
-                else:
-                    width = int(spec.window[1]) - int(spec.window[0])
-                    specs.append(dataclasses.replace(
-                        spec, window=(int(t_now) - width, int(t_now))))
-                tids_all.append(tid)
-            by_cls: Dict[str, List[int]] = {}
-            for i, spec in enumerate(specs):
-                by_cls.setdefault(self._class_of(spec), []).append(i)
-            serve_now = [c for c in by_cls
-                         if c in (DEFAULT_COST_CLASS, self.HISTORY_CLASS)]
-            deep = [c for c in by_cls
-                    if c not in (DEFAULT_COST_CLASS, self.HISTORY_CLASS)]
-            if deep:
-                serve_now.append(self._next_deep(deep))
-            for cls in serve_now:
-                idxs = by_cls[cls]
-                sub = QueryBatch.make([specs[i] for i in idxs])
-                self._serve_class(cls, sub, [tids_all[i] for i in idxs],
-                                  results)
-            classes_served = tuple(serve_now)
+        with telemetry.request("serve.tick") as root:
+            admitted: List[int] = []
+            arrived: Dict[str, int] = {}    # cost class -> rows admitted NOW
+            while self._pending_admit:
+                tid, spec = self._pending_admit.popleft()
+                self._tenants[tid] = spec
+                admitted.append(tid)
+                self.stats.admissions += 1
+                cls = self._class_of(spec)
+                arrived[cls] = arrived.get(cls, 0) + max(1, len(spec.sources))
+            retired: List[int] = []
+            while self._pending_retire:
+                tid = self._pending_retire.popleft()
+                if self._tenants.pop(tid, None) is not None:
+                    retired.append(tid)
+                    self.stats.retirements += 1
+            # stale-forecast flush (the empty-class bug): a class whose last
+            # tenant retired must NOT keep its EWMA/headroom entries — a
+            # re-admission after a quiet gap would inherit the old sticky
+            # headroom and oversize its first bucket.  Classes arriving THIS
+            # tick keep theirs (retire-and-replace churn is the learned rate).
+            live_now = {self._class_of(s) for s in self._tenants.values()}
+            for cls in list(self._admit_ewma):
+                if cls not in live_now and cls not in arrived:
+                    self._admit_ewma.pop(cls, None)
+                    self._admit_hr.pop(cls, None)
+            # arrival-rate EWMA (rows/tick) per cost class: decays every tick,
+            # spikes on bursts — bucket_headroom() reads it so the class's
+            # buckets are already sized when the NEXT burst lands
+            for cls in set(self._admit_ewma) | set(arrived):
+                prev = self._admit_ewma.get(cls, 0.0)
+                self._admit_ewma[cls] = (
+                    (1.0 - self.EWMA_ALPHA) * prev
+                    + self.EWMA_ALPHA * arrived.get(cls, 0))
+                # sticky headroom: grow on a higher forecast NOW (the next
+                # burst is what the headroom exists for), shrink only when
+                # the forecast collapses 4x (the ladder's hysteresis rule) —
+                # a raw ceil of the decaying EWMA would flap capacities
+                want = int(np.ceil(self._admit_ewma[cls]
+                                   * self.HEADROOM_SAFETY))
+                held = self._admit_hr.get(cls, 0)
+                if want > held or want < held // 4:
+                    self._admit_hr[cls] = want
+            self.stats.ticks += 1
+            tick_no = self.stats.ticks
+            results: Dict[int, Any] = {}
+            classes_served: Tuple[str, ...] = ()
+            if self._tenants:
+                # the instantaneous batch: every live tenant's window slid
+                # to end at t_now (width preserved from the submitted
+                # template) — EXCEPT pinned tenants, whose historical window
+                # is the whole point: it serves verbatim through the cold
+                # tier every tick
+                tids_all: List[int] = []
+                specs: List[QuerySpec] = []
+                for tid, spec in self._tenants.items():
+                    if spec.pinned:
+                        specs.append(spec)
+                    else:
+                        width = int(spec.window[1]) - int(spec.window[0])
+                        specs.append(dataclasses.replace(
+                            spec, window=(int(t_now) - width, int(t_now))))
+                    tids_all.append(tid)
+                by_cls: Dict[str, List[int]] = {}
+                for i, spec in enumerate(specs):
+                    by_cls.setdefault(self._class_of(spec), []).append(i)
+                serve_now = [c for c in by_cls
+                             if c in (DEFAULT_COST_CLASS, self.HISTORY_CLASS)]
+                deep = [c for c in by_cls
+                        if c not in (DEFAULT_COST_CLASS, self.HISTORY_CLASS)]
+                if deep:
+                    serve_now.append(self._next_deep(deep))
+                for cls in serve_now:
+                    idxs = by_cls[cls]
+                    sub = QueryBatch.make([specs[i] for i in idxs])
+                    self._serve_class(cls, sub, [tids_all[i] for i in idxs],
+                                      results)
+                classes_served = tuple(serve_now)
         return TickReport(
             tick=tick_no, t_now=int(t_now), classes_served=classes_served,
             admitted=tuple(admitted), retired=tuple(retired),
-            results=results, latency_s=time.perf_counter() - t_start)
+            results=results, latency_s=root.duration)
 
     @property
     def devices(self) -> int:

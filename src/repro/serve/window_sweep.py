@@ -113,6 +113,7 @@ from repro.engine.plan import (
     plan_query,
     rung,
 )
+from repro import telemetry
 from repro.distributed.compat import shard_map as _compat_shard_map
 from repro.distributed.query_shard import (
     query_mesh,
@@ -627,9 +628,10 @@ def _assemble(prev, sub, row_map, new_pos, n_outputs: int):
     def one(p, s):
         return p[rm].at[npos].set(s)
 
-    if n_outputs == 1:
-        return one(prev, sub)
-    return tuple(one(prev[i], sub[i]) for i in range(n_outputs))
+    with jax.named_scope("assemble"):
+        if n_outputs == 1:
+            return one(prev, sub)
+        return tuple(one(prev[i], sub[i]) for i in range(n_outputs))
 
 
 def _gather_rows(prev, row_map, n_outputs: int):
@@ -642,9 +644,10 @@ def _gather_rows(prev, row_map, n_outputs: int):
     if len(row_map) == n_prev and row_map == tuple(range(len(row_map))):
         return prev
     rm = jnp.asarray(row_map, jnp.int32)
-    if n_outputs == 1:
-        return prev[rm]
-    return tuple(p[rm] for p in prev)
+    with jax.named_scope("assemble"):
+        if n_outputs == 1:
+            return prev[rm]
+        return tuple(p[rm] for p in prev)
 
 
 def _gather_solved(sub, solve_map, n_outputs: int):
@@ -654,9 +657,10 @@ def _gather_solved(sub, solve_map, n_outputs: int):
     None`` at schedule build, so the steady no-duplicate batch pays
     nothing."""
     sm = jnp.asarray(solve_map, jnp.int32)
-    if n_outputs == 1:
-        return sub[sm]
-    return tuple(s[sm] for s in sub)
+    with jax.named_scope("assemble"):
+        if n_outputs == 1:
+            return sub[sm]
+        return tuple(s[sm] for s in sub)
 
 
 def _mesh_shape(mesh) -> Tuple[int, int]:
@@ -748,6 +752,19 @@ def _solve_rows_sharded(entry, params, plan, n_vertices, mesh, edges,
     return (sub[0] if entry.n_outputs == 1 else sub), jnp.max(rounds)
 
 
+def _solve_new_rows(algorithm, params, plan, n_vertices, mesh, edges,
+                    windows, sources, init):
+    """One group's solve of its new rows, named ``fixpoint.<algorithm>``
+    in the program; row-sharded over the query axis under a ``mesh``."""
+    entry = _ALGOS[algorithm]
+    with jax.named_scope(f"fixpoint.{algorithm}"):
+        if mesh is None:
+            return entry.solve(edges, windows, sources, plan, n_vertices,
+                               init, dict(params))
+        return _solve_rows_sharded(entry, params, plan, n_vertices, mesh,
+                                   edges, windows, sources, init)
+
+
 def _solve_groups(edges, plan, n_vertices, schedule, prev_results,
                   new_windows, new_sources, inits, maps=None, mesh=None):
     """The dispatch-table core of the fused step: every group's solve (of
@@ -775,43 +792,35 @@ def _solve_groups(edges, plan, n_vertices, schedule, prev_results,
             n_new_cap = entry_s[4]
             sel = jnp.asarray(maps[gi], jnp.int32)
             if n_new_cap:
-                if mesh is None:
-                    sub, rounds = entry.solve(
-                        edges, new_windows[gi], new_sources[gi], plan,
-                        n_vertices, inits[gi], dict(params))
-                else:
-                    # bucketed × mesh (§7.7): the solve capacity is padded
-                    # to a bucket-aligned multiple of the query-axis size
-                    # at schedule build, so the bucketed rows shard exactly
-                    # like exact-schedule rows do
-                    sub, rounds = _solve_rows_sharded(
-                        entry, params, plan, n_vertices, mesh, edges,
-                        new_windows[gi], new_sources[gi], inits[gi])
+                # bucketed × mesh (§7.7): the solve capacity is padded to a
+                # bucket-aligned multiple of the query-axis size at
+                # schedule build, so the bucketed rows shard exactly like
+                # exact-schedule rows do
+                sub, rounds = _solve_new_rows(
+                    algorithm, params, plan, n_vertices, mesh, edges,
+                    new_windows[gi], new_sources[gi], inits[gi])
                 subs = sub if isinstance(sub, tuple) else (sub,)
                 if prev is None:
                     pool = subs
                 else:
                     prevs = prev if isinstance(prev, tuple) else (prev,)
-                    pool = tuple(
-                        jnp.concatenate([p, s], axis=0)
-                        for p, s in zip(prevs, subs))
+                    with jax.named_scope("assemble"):
+                        pool = tuple(
+                            jnp.concatenate([p, s], axis=0)
+                            for p, s in zip(prevs, subs))
             else:
                 rounds = jnp.int32(-1)
                 pool = prev if isinstance(prev, tuple) else (prev,)
-            picked = tuple(p[sel] for p in pool)
+            with jax.named_scope("assemble"):
+                picked = tuple(p[sel] for p in pool)
             out.append(picked[0] if entry.n_outputs == 1 else picked)
             rounds_out.append(rounds)
             continue
         row_map, new_pos, solve_map = entry_s[2], entry_s[3], entry_s[4]
         if new_pos:
-            if mesh is None:
-                sub, rounds = entry.solve(
-                    edges, new_windows[gi], new_sources[gi], plan,
-                    n_vertices, inits[gi], dict(params))
-            else:
-                sub, rounds = _solve_rows_sharded(
-                    entry, params, plan, n_vertices, mesh, edges,
-                    new_windows[gi], new_sources[gi], inits[gi])
+            sub, rounds = _solve_new_rows(
+                algorithm, params, plan, n_vertices, mesh, edges,
+                new_windows[gi], new_sources[gi], inits[gi])
             if solve_map is not None:
                 sub = _gather_solved(sub, solve_map, entry.n_outputs)
             res = sub if prev is None else _assemble(
@@ -909,22 +918,24 @@ def _fused_step_ring(
     mesh: Optional[Mesh] = None,
 ):
     _trace_event((method, capacity, delta_budget, schedule, mesh))
-    if mesh is not None and len(mesh.axis_names) > 1:
-        # 2-D edge×query mesh (§7.7): the ring is sharded along its slot
-        # axis, so the delta scatter runs shard-local (only the owning
-        # edge shard lands each entering slot) — with the solves below it
-        # is still ONE SPMD program, one dispatch per device per advance
-        edges = _advance_ring_sharded(
-            mesh, fields, perm, edges, positions,
-            capacity=capacity, delta_budget=delta_budget)
-    else:
-        # under a 1-D query mesh the inputs are replicated, so the delta
-        # scatter runs per device on that device's whole ring replica —
-        # the SPMD program is still ONE dispatch per device per advance
-        # (§7.5)
-        edges = _ADVANCE_RING[method](
-            fields, perm, edges, positions[0], positions[1], positions[2],
-            capacity=capacity, delta_budget=delta_budget)
+    with jax.named_scope("ring.advance"):
+        if mesh is not None and len(mesh.axis_names) > 1:
+            # 2-D edge×query mesh (§7.7): the ring is sharded along its
+            # slot axis, so the delta scatter runs shard-local (only the
+            # owning edge shard lands each entering slot) — with the
+            # solves below it is still ONE SPMD program, one dispatch per
+            # device per advance
+            edges = _advance_ring_sharded(
+                mesh, fields, perm, edges, positions,
+                capacity=capacity, delta_budget=delta_budget)
+        else:
+            # under a 1-D query mesh the inputs are replicated, so the
+            # delta scatter runs per device on that device's whole ring
+            # replica — the SPMD program is still ONE dispatch per device
+            # per advance (§7.5)
+            edges = _ADVANCE_RING[method](
+                fields, perm, edges, positions[0], positions[1],
+                positions[2], capacity=capacity, delta_budget=delta_budget)
     results, rounds = _solve_groups(
         edges, plan, n_vertices, schedule, prev_results, new_windows,
         new_sources, inits, maps=maps, mesh=mesh)
@@ -1092,7 +1103,9 @@ def _advance(
                 g, tger, prev_plan, union):
             p = prev_plan
         if p is None:
-            p = plan_builder()
+            with telemetry.span("plan.build"):
+                p = plan_builder()
+        telemetry.end("serve.plan")
         if tier != "hot":
             # tiered rebuild (DESIGN.md §7.8): the view is stitched
             # host-side from the cold store's compacted chunks — plus the
@@ -1104,8 +1117,12 @@ def _advance(
             capacity = p.ring_capacity or p.budget
             fields_np, mask_np, lo, hi = coldstore.ring_stitch(
                 union, capacity)
-            edges = EdgeView(
-                *(jnp.asarray(a) for a in fields_np), jnp.asarray(mask_np))
+            with telemetry.span("cold.upload"):
+                edges = EdgeView(
+                    *(jnp.asarray(a) for a in fields_np),
+                    jnp.asarray(mask_np))
+            telemetry.count("cold.upload_bytes", mask_np.nbytes + sum(
+                a.nbytes for a in fields_np))
         else:
             _note("cold:view")
             edges, lo, hi, capacity = ring_view_for_plan(g, tger, union, p)
@@ -1127,13 +1144,14 @@ def _advance(
             _note("cold:solve")
             u_sources, u_windows, inverse = dedup_rows(sources, wins)
             n_unique += len(u_sources)
-            src_dev = (
-                None if entry.source_free
-                else jnp.asarray(u_sources, jnp.int32)
-            )
-            res, rnd = entry.solve(
-                edges, jnp.asarray(u_windows), src_dev, p, g.n_vertices,
-                None, dict(key[1]))
+            with telemetry.span("serve.dispatch"):
+                src_dev = (
+                    None if entry.source_free
+                    else jnp.asarray(u_sources, jnp.int32)
+                )
+                res, rnd = entry.solve(
+                    edges, jnp.asarray(u_windows), src_dev, p, g.n_vertices,
+                    None, dict(key[1]))
             out_map = tuple(inverse)
             if bucketed:
                 # pad the buffer to the bucket capacity, replicating the
@@ -1378,10 +1396,13 @@ def _advance(
         (schedule, prev_results, new_windows, new_sources, inits,
          maps_t, any_warm, n_unique) = built()
         _note(f"fused:scan{shard_tag}")
-        results, rounds = _call_donating(
-            _fused_step_scan,
-            fields, p, prev_results, new_windows, new_sources, inits,
-            maps_t, n_vertices=g.n_vertices, schedule=schedule, mesh=mesh)
+        telemetry.end("serve.plan")
+        with telemetry.span("serve.dispatch"):
+            results, rounds = _call_donating(
+                _fused_step_scan,
+                fields, p, prev_results, new_windows, new_sources, inits,
+                maps_t, n_vertices=g.n_vertices, schedule=schedule,
+                mesh=mesh)
         return results, freeze(
             p, state.edges, -1, -1, 0, results, "reuse", total_new,
             any_warm, rounds, n_unique=n_unique, last_schedule=schedule)
@@ -1418,13 +1439,15 @@ def _advance(
         # delta rung floored at C/8: at most four delta variants per
         # capacity ever compile, pinning the fused cache over long horizons
         delta_budget = min(max(rung(max(shift, 1)), C // 8), C)
-        results, edges, rounds = _call_donating(
-            _fused_step_ring,
-            fields, perm, p, state.edges, prev_results, new_windows,
-            new_sources, inits, maps_t,
-            np.asarray([state.lo, lo_new, hi_new], np.int32),
-            method=p.method, n_vertices=g.n_vertices, capacity=C,
-            delta_budget=delta_budget, schedule=schedule, mesh=mesh)
+        telemetry.end("serve.plan")
+        with telemetry.span("serve.dispatch"):
+            results, edges, rounds = _call_donating(
+                _fused_step_ring,
+                fields, perm, p, state.edges, prev_results, new_windows,
+                new_sources, inits, maps_t,
+                np.asarray([state.lo, lo_new, hi_new], np.int32),
+                method=p.method, n_vertices=g.n_vertices, capacity=C,
+                delta_budget=delta_budget, schedule=schedule, mesh=mesh)
         if coldstore is not None and p.method == "index":
             # compaction hook (§7.8): strictly AFTER the donated step has
             # returned — the positions this slide evicted
@@ -1481,6 +1504,10 @@ def _history_tier(tger, union, state, coldstore, plan_arg, access) -> str:
     return coldstore.classify(union, hot_lo=hot_lo)
 
 
+# the plan phase (grouping, tier, row matching, dedup, schedule; a cold
+# serve's plan) opens with the call and ``_advance`` ends it where it
+# dispatches
+@telemetry.span("serve.plan")
 def serve_batch(
     g: TemporalGraph,
     batch: QueryBatch,

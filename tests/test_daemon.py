@@ -37,6 +37,7 @@ import os
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.core.tger import build_tger
 from repro.data.generators import power_law_temporal_graph
 from repro.engine import (
@@ -443,7 +444,9 @@ def test_daemon_churn_soak():
     assert s.admissions == n_spawned
     assert s.retirements == n_spawned - len(live)
     assert len(server.tenants) == len(live)
-    assert len(server.latencies) == s.advances
+    class_serves = [sp for r in telemetry.recent(s.ticks)
+                    for sp in r.named("serve.class")]
+    assert len(class_serves) == s.advances
     assert s.dispatches >= s.advances        # >= one dispatch-site per serve
     assert s.fused_dispatches + s.cold_advances <= s.dispatches
 
