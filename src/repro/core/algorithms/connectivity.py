@@ -14,6 +14,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.edgemap import (
     EdgeView,
     combine_for_plan,
@@ -81,6 +82,17 @@ def temporal_cc(
     return labels
 
 
+def _cc_compacts(edges: EdgeView, plan: AccessPlan, n_vertices: int,
+                 init) -> bool:
+    """Whether the dense batched solve runs in the view's own vertex space
+    (DESIGN.md §7.4): a cold solve (``init`` None: warm-start labels name
+    vertices of the whole graph) over an unsharded edge axis (the vertex
+    list of an edge shard is local to its device) whose 2·E' endpoints are
+    at most a quarter of V.  Static: it reads shapes and the plan only."""
+    return (init is None and plan.edge_axis is None
+            and 2 * edges.src.shape[0] <= n_vertices // 4)
+
+
 @functools.partial(jax.jit, static_argnames=("n_vertices", "max_rounds"))
 @jax.named_scope("fixpoint.cc")
 def _temporal_cc_over_view_dense(
@@ -99,9 +111,30 @@ def _temporal_cc_over_view_dense(
     valid = runner.valid                               # [Q, E']
     V = n_vertices
     Q = runner.windows.shape[0]
+    compact = _cc_compacts(edges, plan, V, init)
+    if compact:
+        # number the vertices that live slots touch densely in vertex
+        # order (a prefix count, not a sort: a sort of 2·E' keys takes
+        # the chip's compiler seconds); labels live on these K = 2·E'
+        # compact ids, so every min and the pointer jump run as in the
+        # dense round restricted to touched vertices.  ``uniq`` maps a
+        # compact id back to its vertex, V past the touched ones; a
+        # masked slot's lanes take id 0 and are never valid.
+        E = edges.src.shape[0]
+        ends = jnp.concatenate([edges.src, edges.dst])
+        live = jnp.concatenate([edges.mask, edges.mask])
+        touched = jnp.zeros(V, jnp.int32).at[
+            jnp.where(live, ends, V)].set(1, mode="drop")
+        comp = jnp.where(live, (jnp.cumsum(touched) - touched)[ends], 0)
+        uniq = jnp.full(2 * E, V, jnp.int32).at[
+            jnp.where(live, comp, 2 * E)].set(ends, mode="drop")
+        src, dst, n, use_layout = comp[:E], comp[E:], 2 * E, False
+    else:
+        src, dst, n, use_layout = (edges.src, edges.dst, V,
+                                   runner.use_layout)
     labels0 = (
-        jnp.broadcast_to(jnp.arange(V, dtype=jnp.int32), (Q, V)) if init is None
-        else jnp.asarray(init, jnp.int32)
+        jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (Q, n))
+        if init is None else jnp.asarray(init, jnp.int32)
     )
 
     def cond(state):
@@ -110,12 +143,11 @@ def _temporal_cc_over_view_dense(
 
     def body(state, rnd):
         labels, _ = state
-        lab_src = labels[:, edges.src]                 # [Q, E']
-        lab_dst = labels[:, edges.dst]
-        fwd = combine_windows_for_plan(plan, lab_src, edges.dst, V, "min",
-                                       masks=valid,
-                                       use_layout=runner.use_layout)
-        bwd = combine_windows_for_plan(plan, lab_dst, edges.src, V, "min",
+        lab_src = labels[:, src]                       # [Q, E']
+        lab_dst = labels[:, dst]
+        fwd = combine_windows_for_plan(plan, lab_src, dst, n, "min",
+                                       masks=valid, use_layout=use_layout)
+        bwd = combine_windows_for_plan(plan, lab_dst, src, n, "min",
                                        masks=valid)
         new_labels = jnp.minimum(labels, jnp.minimum(fwd, bwd))
         new_labels = jnp.minimum(
@@ -125,6 +157,10 @@ def _temporal_cc_over_view_dense(
         return new_labels, changed
 
     labels, _ = runner.run(cond, body, (labels0, jnp.bool_(True)))
+    if compact:
+        # untouched vertices keep their own id; sentinel slots drop
+        labels = jnp.broadcast_to(jnp.arange(V, dtype=jnp.int32), (Q, V)
+                                  ).at[:, uniq].set(uniq[labels], mode="drop")
     return labels
 
 
@@ -197,7 +233,13 @@ def temporal_cc_over_view(
     Under a ladder-enabled plan a host-level call runs the frontier-rung
     ladder (DESIGN.md §7.9) with the changed-vertex set as the frontier
     and BOTH propagation directions gathered through dual companions
-    (by-source and by-dst) — bit-identical to the dense sweep per round."""
+    (by-source and by-dst) — bit-identical to the dense sweep per round.
+
+    Otherwise a cold solve over a view whose 2·E' endpoints are at most a
+    quarter of V runs in the view's own vertex space (bit-identical, round
+    for round).  A host-level call that does so adds 1 to the request's
+    counter ``cc.compact_solves``; a call under a trace (inside a fused
+    serving step or a caller's jit) adds nothing."""
     if sources is not None:
         raise ValueError("temporal_cc is source-free: pass sources=None")
     if ladder_eligible(plan, edges, windows, init):
@@ -220,6 +262,9 @@ def temporal_cc_over_view(
             max_rounds=runner.max_rounds,
         )
         return labels
+    if _cc_compacts(edges, plan, n_vertices, init) and not any(
+            isinstance(a, jax.core.Tracer) for a in (edges.src, windows)):
+        telemetry.count("cc.compact_solves", 1)
     return _temporal_cc_over_view_dense(
         edges, windows, plan=plan, n_vertices=n_vertices,
         max_rounds=max_rounds, init=init,
