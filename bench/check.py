@@ -1,8 +1,9 @@
 """Decide ``correct``: the answers the timed path served against the plain
-reference, each number beside its limit.
+reference that the deployment names (its ``reference`` file, a module
+``ref`` with ``window_edges`` and ``solve``), each number beside its limit.
 
 - ``failed``: requests of the window that raised instead of answering.
-- ``rows_differ``: integer rows (earliest arrival, reachability, bfs, cc)
+- ``rows_differ``: rows of every algorithm but PageRank (integer or boolean)
   that are not bit-identical to the reference, plus rows of any algorithm
   that are missing or misshapen.  The answers are exact, so the limit is 0.
 - ``pagerank_l1``: over the PageRank rows, the largest
@@ -16,11 +17,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import reference as ref
 from bench.traffic import Ask
 
 
-def reference_rows(asks: List[Ask], cols,
+def reference_rows(asks: List[Ask], cols, ref,
                    solve: Optional[Callable] = None) -> Dict[Ask, tuple]:
     """The reference answer of every distinct request, one window's edges
     selected once."""
@@ -38,17 +38,18 @@ def reference_rows(asks: List[Ask], cols,
     return out
 
 
-def control_rows(served: List[Tuple[Ask, tuple]], cols,
-                 shift: int) -> List[Tuple[Ask, tuple]]:
+def control_rows(served: List[Tuple[Ask, tuple]], cols, shift: int,
+                 ref) -> List[Tuple[Ask, tuple]]:
     """The control in the program's place: PageRank rows from the
     bfloat16 iteration, every other row the reference's answer for the
     request's window moved by ``shift`` seconds (a stale answer)."""
     asks = [a for a, _ in served]
     low = reference_rows(
-        [a for a in asks if a.algorithm == "pagerank"], cols,
+        [a for a in asks if a.algorithm == "pagerank"], cols, ref,
         solve=lambda we, a: (ref.pagerank_lowp(we, **dict(a.params)),))
     stale = reference_rows(
-        [a.shifted(shift) for a in asks if a.algorithm != "pagerank"], cols)
+        [a.shifted(shift) for a in asks if a.algorithm != "pagerank"], cols,
+        ref)
     return [(a, low[a] if a.algorithm == "pagerank" else stale[a.shifted(shift)])
             for a in asks]
 

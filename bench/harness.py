@@ -2,12 +2,26 @@
 measure, check, report.
 
 Everything that belongs to one cell is found from ``BENCHMARK.json`` by
-name: the deployment ``bench/configs/<config>.json``, the traffic mix
-``bench/traffic/<traffic>.json`` (its ``mode`` picks the client in
-``bench/serving.py``), and each per-layer metric's reader
-``bench/metrics/<metric>.py`` (a ``read(record)`` that returns a number or
-None).  Adding a cell, a mix, a deployment or a metric adds files and
-entries and edits nothing here.
+name, and every Python file it names is loaded by :func:`load_module`:
+
+- the deployment ``bench/configs/<config>.json``.  Its ``reference`` names
+  the plain reference, a file with ``window_edges(cols, window)``,
+  ``solve(window_edges, algorithm, source, params)`` and, where the cell
+  serves PageRank, the control's ``pagerank_lowp``.  Its ``generator``, where
+  given, names a file whose ``generate(cfg, seed)`` draws the edge columns
+  (``bench.deployment.Columns``); without it ``bench/deployment.py`` draws a
+  SNAP-shaped power-law graph.  The program builds either the same way.
+- the traffic mix ``bench/traffic/<traffic>.json``.  Its ``mode`` picks a
+  client of ``bench/serving.py`` (``batch``, ``history``) or else the
+  ``Client`` of ``bench/modes/<mode>.py``, built as
+  ``Client(deployment, mix, seed, seconds, spans)`` with the interface of
+  ``serving.BatchClient``.
+- each per-layer metric's reader ``bench/metrics/<metric>.py``, a
+  ``read(record)`` that returns a number or None.
+
+So a cell, a mix of a new mode, a deployment of its own shape with its own
+reference, or a metric is added by new files and entries alone, and edits
+nothing here.
 """
 from __future__ import annotations
 
@@ -16,6 +30,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -49,13 +64,40 @@ def load_traffic(name: str, root: str = ROOT) -> dict:
     return _json(root, "traffic", f"{name}.json")
 
 
-def load_reader(name: str, root: str = ROOT):
-    path = os.path.join(root, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench.metrics." + name.replace(".", "_"), path)
+def load_module(path: str, root: str = ROOT):
+    """The Python file ``path`` of the checkout (relative to ``root``),
+    executed anew as a module of its own."""
+    full = os.path.realpath(os.path.join(root, path))
+    if not full.startswith(os.path.join(os.path.realpath(root), "")):
+        raise ValueError(f"{path!r} is not a file of the checkout")
+    name = "bench_files." + re.sub(r"\W", "_", path[:-len(".py")])
+    spec = importlib.util.spec_from_file_location(name, full)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, root: str = ROOT):
+    return load_module(f"bench/metrics/{name}.py", root).read
+
+
+def load_generator(cfg: dict, root: str = ROOT):
+    """The deployment's ``generate(cfg, seed)``."""
+    if "generator" not in cfg:
+        from bench import deployment
+
+        return deployment.generate
+    return load_module(cfg["generator"], root).generate
+
+
+def load_client(mode: str, root: str = ROOT):
+    """The client class of a traffic mode."""
+    from bench import serving
+
+    if mode in serving.CLIENTS:
+        return serving.CLIENTS[mode]
+    return load_module(f"bench/modes/{mode}.py", root).Client
 
 
 def cell(spec: dict, name: str) -> dict:
@@ -160,26 +202,29 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     numbers (``bench/control.py``; never in the benchmark's own runs)."""
     import jax
 
-    from bench import check, deployment, serving
+    from bench import check, deployment
     from bench import trace as tracing
 
     spec = spec or load_spec(root)
     w = cell(spec, workload)
     cfg = config or load_config(w["config"], root)
     mix = load_traffic(w["traffic"], root)
+    generate = load_generator(cfg, root)
+    ref = load_module(cfg["reference"], root)
+    client_type = load_client(mix["mode"], root)
     dev = jax.devices()[0]
     meter = CompileMeter()
     spans = Spans()
 
     t = time.perf_counter()
-    cols = deployment.generate(cfg, seed)
+    cols = generate(cfg, seed)
     log(f"[generate] {cfg['name']} vertices={cols.n_vertices} "
         f"edges={cols.n_edges} seconds={time.perf_counter() - t}")
 
     t_setup = time.perf_counter()
     dep = deployment.build(cfg, cols)
     t_build = time.perf_counter() - t_setup
-    client = serving.CLIENTS[mix["mode"]](dep, mix, seed, seconds, spans)
+    client = client_type(dep, mix, seed, seconds, spans)
     client.warm()
     setup_s = time.perf_counter() - t_setup
     compiles_setup = meter.n
@@ -219,7 +264,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     client.release()
     del dep
     with spans("reference"):
-        want = check.reference_rows([a for a, _ in served], cols)
+        want = check.reference_rows([a for a, _ in served], cols, ref)
     checks = check.compare(served, want, cfg["limits"], client.failed)
     correct = check.passed(checks)
     log(f"[reference] seconds={spans.seconds['reference']}")
@@ -252,7 +297,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             "device_ops": [list(x) for x in summary.device_ops],
             "idle_gaps": [list(x) for x in summary.idle_gaps]}
     if control:
-        ctrl = check.control_rows(served, cols, client.stale_shift)
+        ctrl = check.control_rows(served, cols, client.stale_shift, ref)
         out["control_checks"] = check.compare(ctrl, want, cfg["limits"], 0)
     out["checks"] = checks
     for name, c in checks.items():

@@ -1,10 +1,15 @@
 """Drive the program's serving path with a traffic mix.
 
-One client per traffic mode, each over ``repro.serve.engine.GraphBatchServer``:
+The built-in clients, one per traffic mode, each over
+``repro.serve.engine.GraphBatchServer``:
 
 - ``batch``: closed-loop ``advance`` of the whole tenant batch;
 - ``history``: closed-loop time-travel ``advance`` of windows the ring has
   evicted into a ``ColdStore``.
+
+A mode of its own is a file ``bench/modes/<mode>.py`` whose ``Client`` has
+the same interface; one that only asks other requests can subclass
+``BatchClient`` and name its own ``requests``.
 
 A client's ``warm`` is set-up: it runs every program shape the timed
 requests will use.  ``measure`` runs the window and returns the end-to-end
@@ -81,13 +86,17 @@ class Reservoir:
 
 
 class BatchClient:
-    """Closed-loop advances of one tenant batch."""
+    """Closed-loop advances of one tenant batch.  ``requests(mix, cols,
+    seed)`` gives the requests: ``advance(k)``, ``max_advances`` and the
+    ``stride`` of a stale answer."""
+
+    requests = tr.Batch
 
     def __init__(self, dep: Deployment, mix: dict, seed: int, seconds: float,
                  spans):
         from repro.serve.engine import GraphBatchServer
 
-        self.gen = tr.Batch(mix, dep.cols, seed)
+        self.gen = self.requests(mix, dep.cols, seed)
         self.stale_shift = -self.gen.stride
         self.mix, self.spans = mix, spans
         self.server = GraphBatchServer(dep.graph, dep.tger,
@@ -113,7 +122,7 @@ class BatchClient:
     def _ea_rounds(self):
         st = self.server.state
         rounds = st.last_rounds
-        if not isinstance(rounds, tuple):
+        if not isinstance(rounds, (tuple, list)):
             rounds = (rounds,)
         for key, r in zip(st.group_keys, rounds):
             if key[0] == "earliest_arrival" and r is not None:

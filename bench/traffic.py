@@ -1,8 +1,9 @@
 """The general traffic generators.  A mix is a data file,
 ``bench/traffic/<mix>.json``, whose ``mode`` names one of the generators
-below and whose other keys are its parameters.  Vertices are drawn by
-rank and labelled as the run's seed labels the graph (``Columns.rank_to_id``),
-so one seed gives one sequence of requests.
+below (or a mode of its own, ``bench/modes/<mode>.py``) and whose other
+keys are its parameters.  Vertices are drawn by rank and labelled as the
+run's seed labels the graph (``Columns.rank_to_id``), so one seed gives
+one sequence of requests.
 
 A request is an :class:`Ask`: an algorithm, a window ``(ta, tb)`` in
 seconds, a source vertex (None for source-free algorithms) and the
@@ -17,7 +18,7 @@ import numpy as np
 
 from bench.deployment import DAY_S, Columns, rng_for, zipf_cdf, zipf_draw
 
-SOURCE_FREE = ("cc", "pagerank")
+SOURCE_FREE = ("cc", "pagerank", "kcore")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,7 +33,8 @@ class Ask:
             self, window=(self.window[0] + dt, self.window[1] + dt))
 
 
-def _params(mix: dict, algorithm: str) -> Tuple[Tuple[str, object], ...]:
+def ask_params(mix: dict, algorithm: str) -> Tuple[Tuple[str, object], ...]:
+    """The mix's parameters of ``algorithm``, as an ``Ask`` holds them."""
     return tuple(sorted(mix.get("params", {}).get(algorithm, {}).items()))
 
 
@@ -74,7 +76,7 @@ class Batch:
             asks.append(Ask(
                 alg, (end - self.width, end),
                 None if alg in SOURCE_FREE else self.sources[i],
-                _params(self.mix, alg)))
+                ask_params(self.mix, alg)))
         return asks
 
 
@@ -103,7 +105,7 @@ class History:
 
     def _query(self, window, source) -> List[Ask]:
         return [Ask(a, window, None if a in SOURCE_FREE else source,
-                    _params(self.mix, a))
+                    ask_params(self.mix, a))
                 for a in self.mix["algorithms"]]
 
     def hot_query(self) -> List[Ask]:

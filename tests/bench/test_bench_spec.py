@@ -89,8 +89,10 @@ def test_cell_resolves_by_name(workload):
     assert entry["file"] == f"bench/configs/{w['config']}.json"
     assert cfg["name"] == w["config"] and cfg["source"] == entry["source"]
     mix = harness.load_traffic(w["traffic"], ROOT)
-    from bench import serving
-    assert mix["mode"] in serving.CLIENTS
+    assert callable(harness.load_client(mix["mode"], ROOT))
+    assert callable(harness.load_generator(cfg, ROOT))
+    ref = harness.load_module(cfg["reference"], ROOT)
+    assert callable(ref.window_edges) and callable(ref.solve)
     for m in harness.per_layer_of(SPEC, workload):
         assert callable(harness.load_reader(m["name"], ROOT))
 
@@ -112,12 +114,9 @@ def test_every_config_and_metric_is_used():
         assert set(m.get("workloads", CELLS)) <= set(CELLS)
 
 
-def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path):
-    """A copy of the benchmark gains a deployment, a traffic mix, a metric
-    and a cell through new files and entries; the harness runs it."""
-    root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench")
-    spec = json.loads(json.dumps(SPEC))
+def _snap_shaped(root, spec):
+    """A SNAP-shaped deployment at other counts, a batch mix of four
+    tenants and a metric, on the stock generator, reference and client."""
     cfg = dict(cellcheck.small(harness.load_config("wiki-talk", ROOT)),
                name="tiny-talk", limits={"rows_differ": 0, "pagerank_l1": 1e-4})
     (root / "bench" / "configs" / "tiny-talk.json").write_text(
@@ -126,21 +125,83 @@ def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path):
     (root / "bench" / "traffic" / "batch4.json").write_text(json.dumps(mix))
     (root / "bench" / "metrics" / "advances.tiny.py").write_text(
         "def read(rec):\n    return rec.ops\n")
-    spec["configs"].append({"name": "tiny-talk", "source": "x",
-                            "file": "bench/configs/tiny-talk.json",
+    _add_cell(spec, "tiny-talk", "batch4", "advances.tiny")
+    return "tiny-talk-batch4", {"bench/reference.py",
+                                "bench/metrics/advances.tiny.py"}
+
+
+def _timetable(root, spec):
+    """A timetable deployment with a generator and a reference of its own
+    (the reference answers kcore, which the stock one lacks) and a mix in a
+    mode of its own (``tests/bench/fixtures/transit``)."""
+    shutil.copytree(TRANSIT, root / "bench", dirs_exist_ok=True)
+    _add_cell(spec, "tiny-transit", "departures-k2", "ea_rounds.tiny")
+    return "tiny-transit-departures-k2", {
+        "bench/timetable.py", "bench/kcore_reference.py",
+        "bench/modes/departures.py", "bench/metrics/ea_rounds.tiny.py"}
+
+
+def _timetable_wrong_reference(root, spec):
+    """The timetable cell with its reference's kcore rows planted wrong."""
+    workload, files = _timetable(root, spec)
+    path = root / "bench" / "kcore_reference.py"
+    text = path.read_text()
+    assert text.count("return (kcore(") == 1
+    path.write_text(text.replace("return (kcore(", "return (~kcore("))
+    return workload, files
+
+
+TRANSIT = os.path.join(os.path.dirname(__file__), "fixtures", "transit")
+ADDED = {"snap-shaped": (_snap_shaped, True),
+         "timetable": (_timetable, True),
+         "timetable-wrong-reference": (_timetable_wrong_reference, False)}
+
+
+def _add_cell(spec, config, traffic, metric):
+    spec["configs"].append({"name": config, "source": "x",
+                            "file": f"bench/configs/{config}.json",
                             "reduced": [], "why": "x"})
-    spec["workloads"].append({"name": "tiny-batch4", "config": "tiny-talk",
-                              "traffic": "batch4", "chips": 1, "why": "x"})
+    name = f"{config}-{traffic}"
+    spec["workloads"].append({"name": name, "config": config,
+                              "traffic": traffic, "chips": 1, "why": "x"})
     next(m for m in spec["end_to_end"]
-         if m["name"] == "advance_s")["workloads"].append("tiny-batch4")
-    spec["per_layer"].append({"name": "advances.tiny", "unit": "count",
-                              "better": "higher", "source": "host_clock",
+         if m["name"] == "advance_s")["workloads"].append(name)
+    spec["per_layer"].append({"name": metric, "unit": "count",
+                              "better": "higher", "source": "program_counter",
                               "layer": "batch serving", "moves": "advance_s",
-                              "workloads": ["tiny-batch4"]})
+                              "workloads": [name]})
+
+
+@pytest.mark.parametrize("case", ADDED)
+def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path, monkeypatch,
+                                                     case):
+    """A copy of the benchmark gains a deployment, a traffic mix, a metric
+    and a cell through new files and entries; the harness runs it, loads
+    only the files they name, and judges it by the deployment's own
+    reference."""
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench")
+    spec = json.loads(json.dumps(SPEC))
+    add, sound = ADDED[case]
+    workload, files = add(root, spec)
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    out = harness.run("tiny-batch4", 3, 0.2, False, root=str(root))
-    assert out["correct"], out["checks"]
+    loaded = []
+    real = harness.load_module
+
+    def load_module(path, root=harness.ROOT):
+        loaded.append(path)
+        return real(path, root)
+
+    monkeypatch.setattr(harness, "load_module", load_module)
+    out = harness.run(workload, 3, 0.2, False, root=str(root))
+    assert out["correct"] is sound, out["checks"]
+    assert out["failed"] == 0 and out["checks"]["rows_checked"]["value"] >= 4
+    if not sound:
+        assert out["checks"]["rows_differ"]["value"] >= 1
+        return
     assert set(out["metrics"]) == {"setup_s", "advance_s"}
-    out = harness.run("tiny-batch4", 3, 0.2, True, root=str(root))
+    out = harness.run(workload, 3, 0.2, True, root=str(root))
     assert out["correct"], out["checks"]
-    assert out["metrics"]["advances.tiny"]["value"] >= 1
+    (metric,) = (m["name"] for m in harness.per_layer_of(spec, workload))
+    assert out["metrics"][metric]["value"] >= 1
+    assert set(loaded) == files

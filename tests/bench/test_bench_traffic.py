@@ -1,6 +1,8 @@
 """Every deployment keeps its source's published counts, every seed serves
 the same graph under other labels, and every traffic generator gives one
 sequence of requests per seed."""
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -28,6 +30,35 @@ def test_columns_repeat_per_seed(seed):
     for f in ("src", "dst", "t_start", "t_end", "rank_to_id"):
         assert np.array_equal(getattr(a, f), getattr(b, f))
     assert (a.t_end >= a.t_start).all() and (a.src != a.dst).all()
+
+
+# SHA-256 of the five columns drawn at the small size, as the benchmark
+# drew them when deployments could first bring a generator of their own:
+# a change to the stock draw changes what every existing cell serves.
+PINNED = {
+    ("sx-stackoverflow", 5):
+        "0948c8c7b12fe79fd9bfca0694189c21c56cf913865c0552f08bc8420d538ecf",
+    ("sx-stackoverflow", 2**31 + 7):
+        "7e78356eb2dc566055c94c0e47afc22dc3fa783b319098a28d10ec73583f1fa5",
+    ("wiki-talk", 5):
+        "24e60a8bf4683f59fcd73138423cff1d6572eb9c2c9eeb245e001b298c960017",
+    ("wiki-talk", 2**31 + 7):
+        "d93fc9c6219d4f326787606ed881f577d52a471b2216d262e6bdeff70962e403",
+}
+
+
+@pytest.mark.parametrize("config, seed", PINNED)
+def test_existing_deployments_draw_the_pinned_columns(config, seed):
+    cfg = harness.load_config(config, cellcheck.ROOT)
+    assert "generator" not in cfg
+    cols = deployment.generate(cellcheck.small(cfg), seed)
+    h = hashlib.sha256()
+    for f in ("src", "dst", "t_start", "t_end", "rank_to_id"):
+        a = np.ascontiguousarray(getattr(cols, f))
+        h.update(f.encode())
+        h.update(str(a.dtype).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == PINNED[config, seed]
 
 
 @pytest.mark.parametrize("seed", SEEDS)
