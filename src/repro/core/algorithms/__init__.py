@@ -4,6 +4,7 @@ from repro.core.algorithms.paths import (  # noqa: F401
     earliest_arrival_multi,
     earliest_arrival_over_view,
     latest_departure,
+    latest_departure_over_view,
     fastest,
     shortest_duration,
 )

@@ -374,16 +374,7 @@ def latest_departure(
     ld0 = jnp.full(V, INT_NEG_INF, jnp.int32).at[target].set(tb)
     frontier0 = frontier_from_sources(V, target)
 
-    def relax(edges, ld_dst):
-        # chaining (u,v,[ts,te]) before the continuation leaving v at ld[v]:
-        # succeeds: te <= ld[v]; strict: te < ld[v].
-        if pred is OrderingPredicateType.STRICTLY_SUCCEEDS:
-            ok = edges.t_end < ld_dst
-        elif pred is OrderingPredicateType.SUCCEEDS:
-            ok = edges.t_end <= ld_dst
-        else:
-            raise ValueError("latest_departure supports succeeds predicates")
-        return edges.t_start, ok
+    relax = _ld_relax(pred)
 
     def cond(state):
         _, frontier = state
@@ -398,6 +389,75 @@ def latest_departure(
 
     ld, _ = runner.run(cond, body, (ld0, frontier0))
     return ld
+
+
+def _ld_relax(pred: OrderingPredicateType):
+    if pred not in (OrderingPredicateType.SUCCEEDS,
+                    OrderingPredicateType.STRICTLY_SUCCEEDS):
+        raise ValueError("latest_departure supports succeeds predicates")
+
+    def relax(edges, ld_dst):
+        # chaining (u,v,[ts,te]) before the continuation leaving v at ld[v]:
+        # succeeds: te <= ld[v]; strict: te < ld[v].
+        if pred is OrderingPredicateType.STRICTLY_SUCCEEDS:
+            ok = edges.t_end < ld_dst
+        else:
+            ok = edges.t_end <= ld_dst
+        return edges.t_start, ok
+
+    return relax
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_vertices", "pred", "max_rounds", "with_rounds"),
+)
+@jax.named_scope("fixpoint.latest_departure")
+def latest_departure_over_view(
+    edges: EdgeView,
+    windows: jax.Array,             # i32[Q, 2]
+    *,
+    plan: AccessPlan,
+    n_vertices: int,
+    sources=None,                   # scalar (broadcast) | i32[Q] targets
+    pred: OrderingPredicateType = OrderingPredicateType.SUCCEEDS,
+    max_rounds: int = 0,
+    init: Optional[jax.Array] = None,   # [Q, V] warm-start departures
+    with_rounds: bool = False,
+):
+    """The batched latest-departure fixpoint over a prebuilt view — the
+    in-direction twin of :func:`earliest_arrival_over_view`: row q is
+    ``latest_departure(g, sources[q], windows[q])`` (``sources`` are the
+    rows' TARGETS), seeded at ``(sources[q], windows[q, 1])`` with every
+    other vertex at INT32_MIN (unreached) and combined with ``max`` over
+    in-edges.  ``init`` replaces the seeded labels (frontier = the reached
+    vertices); ``with_rounds=True`` returns ``(departure, rounds)``.  The
+    serving step runs it dense; there is no ladder path."""
+    runner = FixpointRunner.for_view(
+        edges, windows=windows, sources=sources, plan=plan,
+        n_vertices=n_vertices, direction="in", max_rounds=max_rounds,
+    )
+    if init is None:
+        ld0 = runner.seeded(INT_NEG_INF, runner.windows[:, 1])
+        frontier0 = runner.source_frontier()
+    else:
+        ld0 = init
+        frontier0 = ld0 > INT_NEG_INF
+    relax = _ld_relax(pred)
+
+    def cond(state):
+        _, frontier = state
+        return jnp.any(frontier)
+
+    def body(state, rnd):
+        ld, frontier = state
+        cand, _ = runner.step(frontier, ld, relax, "max")
+        new_ld = jnp.maximum(ld, cand)
+        return new_ld, new_ld > ld
+
+    (ld, _), rounds = runner.run(cond, body, (ld0, frontier0),
+                                 with_rounds=True)
+    return (ld, rounds) if with_rounds else ld
 
 
 # ---------------------------------------------------------------------------
@@ -545,6 +605,7 @@ __all__ = [
     "earliest_arrival_batched",
     "earliest_arrival_over_view",
     "latest_departure",
+    "latest_departure_over_view",
     "fastest",
     "shortest_duration",
 ]

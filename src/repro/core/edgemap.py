@@ -168,10 +168,11 @@ def view_for_plan(
 # permutation (global for index plans, heavy-only for hybrid plans, with the
 # light partition a window-independent static prefix).  An advance from
 # ``lo`` to ``lo'`` then re-gathers exactly the entering positions
-# [lo + C, lo' + C) — a fixed-shape scatter of a delta-budget rung — and
-# recomputes the O(C) validity mask from the new [lo, hi); every surviving
-# slot's payload is untouched, so the advanced buffer is bit-identical to a
-# cold ring build at the new window (property-tested, wrap-around included).
+# [lo + C, lo' + C) — fixed-shape scatters of ``delta_budget`` positions, as
+# many as the slide needs, in one loop — and recomputes the O(C) validity
+# mask from the new [lo, hi); every surviving slot's payload is untouched,
+# so the advanced buffer is bit-identical to a cold ring build at the new
+# window (property-tested, wrap-around included).
 # ---------------------------------------------------------------------------
 
 def ring_positions(lo, capacity: int) -> jax.Array:
@@ -180,6 +181,28 @@ def ring_positions(lo, capacity: int) -> jax.Array:
     s = jnp.arange(capacity, dtype=jnp.int32)
     lo = jnp.asarray(lo, jnp.int32)
     return lo + jnp.mod(s - lo, capacity)
+
+
+def scatter_entering(arrays, fields, perm, lo_prev, lo_new, *,
+                     capacity: int, chunk: int, slot_of: Callable):
+    """Write the positions [lo_prev + C, lo_new + C) that enter a ring slid
+    forward from ``lo_prev`` to ``lo_new`` into ``arrays`` (the ring's five
+    edge fields), at the slots ``slot_of(enter, ok)`` gives (out of range
+    = dropped).  ``chunk`` positions per step of a loop that runs as many
+    steps as the slide needs, so one compiled program serves every slide
+    up to C."""
+    first = jnp.asarray(lo_prev, jnp.int32) + capacity
+    end = jnp.asarray(lo_new, jnp.int32) + capacity
+
+    def step(i, arrays):
+        enter = first + i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        eids = perm[jnp.minimum(enter, perm.shape[0] - 1)]
+        slots = slot_of(enter, enter < end)
+        return tuple(p.at[slots].set(f[eids], mode="drop")
+                     for p, f in zip(arrays, fields))
+
+    return jax.lax.fori_loop(0, (end - first + chunk - 1) // chunk, step,
+                             tuple(arrays))
 
 
 def _gather_fields(g: TemporalGraph, eids):
@@ -207,15 +230,11 @@ def advance_index_ring_fields(fields, perm, prev: EdgeView, lo_prev, lo_new,
     permutation.  The serving hot loop passes exactly these arrays instead
     of the full graph/TGER pytrees: per-call pytree flattening is real
     dispatch latency at serving budgets."""
-    enter = jnp.asarray(lo_prev, jnp.int32) + capacity + jnp.arange(
-        delta_budget, dtype=jnp.int32)
-    ok = enter < jnp.asarray(lo_new, jnp.int32) + capacity
-    eids = perm[jnp.minimum(enter, perm.shape[0] - 1)]
-    slots = jnp.where(ok, jnp.mod(enter, capacity), capacity)  # OOB -> dropped
-    new = [
-        p.at[slots].set(f[eids], mode="drop")
-        for p, f in zip(prev[:5], fields)
-    ]
+    new = scatter_entering(
+        prev[:5], fields, perm, lo_prev, lo_new, capacity=capacity,
+        chunk=delta_budget,
+        slot_of=lambda enter, ok: jnp.where(
+            ok, jnp.mod(enter, capacity), capacity))   # OOB -> dropped
     return EdgeView(*new, ring_positions(lo_new, capacity) < hi_new)
 
 
@@ -224,8 +243,9 @@ def advance_index_ring(g: TemporalGraph, idx: TGERIndex, prev: EdgeView,
                        delta_budget: int) -> EdgeView:
     """Slide the index ring forward: scatter only the ENTERING positions
     [lo_prev+C, lo_new+C) into the slots they own (the ones being vacated),
-    then recompute the mask.  Requires 0 <= lo_new - lo_prev <= delta_budget
-    <= C (host-checked by the server; it falls cold otherwise)."""
+    ``delta_budget`` of them per scatter, then recompute the mask.
+    Requires 0 <= lo_new - lo_prev <= C (host-checked by the server; it
+    falls cold otherwise)."""
     return advance_index_ring_fields(
         (g.src, g.dst, g.t_start, g.t_end, g.weight), idx.perm_by_start,
         prev, lo_prev, lo_new, hi_new,
@@ -260,15 +280,11 @@ def advance_hybrid_ring_fields(fields, heavy_perm, prev: EdgeView, lo_prev,
     length is recovered from the resident buffer (``len - capacity``), so
     only the five edge arrays and the heavy permutation travel."""
     L = prev.src.shape[0] - capacity
-    enter = jnp.asarray(lo_prev, jnp.int32) + capacity + jnp.arange(
-        delta_budget, dtype=jnp.int32)
-    ok = enter < jnp.asarray(lo_new, jnp.int32) + capacity
-    eids = heavy_perm[jnp.minimum(enter, heavy_perm.shape[0] - 1)]
-    slots = jnp.where(ok, L + jnp.mod(enter, capacity), prev.src.shape[0])
-    new = [
-        p.at[slots].set(f[eids], mode="drop")
-        for p, f in zip(prev[:5], fields)
-    ]
+    new = scatter_entering(
+        prev[:5], fields, heavy_perm, lo_prev, lo_new, capacity=capacity,
+        chunk=delta_budget,
+        slot_of=lambda enter, ok: jnp.where(
+            ok, L + jnp.mod(enter, capacity), prev.src.shape[0]))
     h_mask = ring_positions(lo_new, capacity) < hi_new
     mask = jax.lax.dynamic_update_slice_in_dim(prev.mask, h_mask, L, 0)
     return EdgeView(*new, mask)

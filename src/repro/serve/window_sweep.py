@@ -31,7 +31,8 @@ single-use / moved-from).  Row reuse is per (algorithm, params, source,
 window) row; identical rows across tenants DEDUP to one solved row and
 fan out at assembly; warm starts sit behind the explicit ``warm_start=``
 flag with per-algorithm soundness (EA and cc exact, reachability sound,
-bfs/pagerank/kcore/betweenness refused — DESIGN.md §7.4 soundness table).
+latest departure/bfs/pagerank/kcore/betweenness refused — DESIGN.md §7.4
+soundness table).
 
 ``serve_batch(..., mesh=D)`` SHARDS the batch's row axis across a query
 mesh (DESIGN.md §7.5): ring view and carried results replicated per
@@ -74,6 +75,8 @@ from repro.core.algorithms import (
     earliest_arrival,
     earliest_arrival_batched,
     earliest_arrival_over_view,
+    latest_departure,
+    latest_departure_over_view,
     overlaps_reachability,
     overlaps_reachability_batched,
     overlaps_reachability_over_view,
@@ -99,6 +102,9 @@ from repro.core.edgemap import (
     advance_hybrid_ring_fields,
     advance_index_ring_fields,
     ring_view_for_plan,
+    scatter_entering,
+    union_window,
+    view_for_plan,
 )
 from repro.core.temporal_graph import TemporalGraph
 from repro.core.tger import (
@@ -111,7 +117,6 @@ from repro.engine.plan import (
     per_vertex_window_budget,
     plan_batch,
     plan_query,
-    rung,
 )
 from repro import telemetry
 from repro.distributed.compat import shard_map as _compat_shard_map
@@ -147,9 +152,9 @@ class _Algo(NamedTuple):
 
     ``solve(edges, windows, sources, plan, n_vertices, init, kwargs)`` runs
     the group's rows over a prebuilt (ring) view and returns ``(result,
-    rounds)`` — ``rounds`` is the runner's convergence metric for EA and -1
-    for the vmapped/fixed-iteration algorithms.  ``warm`` builds a
-    containment warm init for new rows (None = warm starts REFUSED — the
+    rounds)`` — ``rounds`` is the runner's convergence metric for EA and
+    latest departure and -1 for the vmapped/fixed-iteration algorithms.
+    ``warm`` builds a containment warm init for new rows (None = warm starts REFUSED — the
     per-algorithm soundness table of DESIGN.md §7.4).  ``n_outputs`` is the
     result-tuple arity (1 = bare [Q, V] array)."""
 
@@ -163,6 +168,12 @@ class _Algo(NamedTuple):
 
 def _solve_ea(edges, windows, sources, plan, n_vertices, init, kwargs):
     return earliest_arrival_over_view(
+        edges, windows, sources=sources, plan=plan, n_vertices=n_vertices,
+        init=init, with_rounds=True, **kwargs)
+
+
+def _solve_ld(edges, windows, sources, plan, n_vertices, init, kwargs):
+    return latest_departure_over_view(
         edges, windows, sources=sources, plan=plan, n_vertices=n_vertices,
         init=init, with_rounds=True, **kwargs)
 
@@ -325,6 +336,12 @@ def _b_ea(g, s, w, t, plan, kw):
     return earliest_arrival_batched(g, s, w, t, plan=plan, **kw)
 
 
+def _b_ld(g, s, w, t, plan, kw):
+    edges = view_for_plan(g, t, union_window(w), plan)
+    return latest_departure_over_view(
+        edges, w, sources=s, plan=plan, n_vertices=g.n_vertices, **kw)
+
+
 def _b_reach(g, s, w, t, plan, kw):
     return overlaps_reachability_batched(g, s, w, t, plan=plan, **kw)
 
@@ -361,6 +378,10 @@ def _s_ea(g, s, w, t, plan, kw):
     return earliest_arrival(g, s, w, t, plan=plan, **kw)
 
 
+def _s_ld(g, s, w, t, plan, kw):
+    return latest_departure(g, s, w, t, plan=plan, **kw)
+
+
 def _s_reach(g, s, w, t, plan, kw):
     return overlaps_reachability(g, s, w, t, plan=plan, **kw)
 
@@ -388,6 +409,7 @@ def _s_betweenness(g, s, w, t, plan, kw):
 
 _ALGOS = {
     "earliest_arrival": _Algo(_solve_ea, _b_ea, _s_ea, 1, False, _ea_warm),
+    "latest_departure": _Algo(_solve_ld, _b_ld, _s_ld, 1, False, None),
     "reachability": _Algo(_solve_reach, _b_reach, _s_reach, 3, False,
                           _reach_warm),
     "pagerank": _Algo(_solve_pagerank, _b_pagerank, _s_pagerank, 1, True,
@@ -592,7 +614,9 @@ class SweepState:
     last_advance: str = "cold"
     n_solved: int = 0
     warm_applied: bool = False   # an explicit warm_start= actually seeded rows
-    last_rounds: Any = None      # i32 device scalar(s) (EA groups; lazy, no sync)
+    last_rounds: tuple = ()      # per group, in group order: i32 device scalar
+                                 # (rounds of its solve; -1 where it solved
+                                 # nothing or runs no convergence loop; lazy)
     mesh: Any = None             # query Mesh of a SHARDED stream (DESIGN.md §7.5)
     n_solved_unique: int = 0     # rows that actually ran a fixpoint after dedup
     group_caps: tuple = ()       # per-group BUCKETED row capacity (§7.6;
@@ -870,18 +894,15 @@ def _advance_ring_sharded(mesh, fields, perm, edges, positions, *,
     def body(fields_l, perm_l, edges_l, pos_l):
         base = jax.lax.axis_index(ax_e) * c_local
         lo_prev, lo_new, hi_new = pos_l[0], pos_l[1], pos_l[2]
-        enter = lo_prev + capacity + jnp.arange(delta_budget,
-                                                dtype=jnp.int32)
-        ok = enter < lo_new + capacity
-        eids = perm_l[jnp.minimum(enter, perm_l.shape[0] - 1)]
-        gslot = jnp.mod(enter, capacity)
-        lslot = jnp.where(
-            ok & (gslot >= base) & (gslot < base + c_local),
-            gslot - base, c_local)                       # OOB -> dropped
-        new = [
-            p.at[lslot].set(f[eids], mode="drop")
-            for p, f in zip(edges_l[:5], fields_l)
-        ]
+
+        def local_slot(enter, ok):
+            gslot = jnp.mod(enter, capacity)
+            return jnp.where(ok & (gslot >= base) & (gslot < base + c_local),
+                             gslot - base, c_local)      # OOB -> dropped
+
+        new = scatter_entering(
+            edges_l[:5], fields_l, perm_l, lo_prev, lo_new,
+            capacity=capacity, chunk=delta_budget, slot_of=local_slot)
         pos = base + jnp.arange(c_local, dtype=jnp.int32)
         pos = lo_new + jnp.mod(pos - lo_new, capacity)
         return EdgeView(*new, pos < hi_new)
@@ -1014,10 +1035,12 @@ def _plan_covers(g, tger, p: AccessPlan, union) -> bool:
 def _group_warm(key, warm_start, new_sources, new_windows, prev, n_vertices):
     """The explicit ``warm_start=`` gate (DESIGN.md §7.2/§7.4): EA and cc
     warm starts are exact, reachability's is sound-but-not-bit-stable
-    (opt-in is the consent to that); bfs (round-indexed hops), pagerank
-    (finite-iteration drift), kcore (peeling cannot resurrect) and
-    betweenness (not a monotone fixpoint) are REFUSED — the caller
-    observes refusals via ``state.warm_applied``."""
+    (opt-in is the consent to that); latest departure (no warm init is
+    built: one-width journey windows never strictly contain each other), bfs
+    (round-indexed hops), pagerank (finite-iteration drift), kcore
+    (peeling cannot resurrect) and betweenness (not a monotone fixpoint)
+    are REFUSED — the caller observes refusals via
+    ``state.warm_applied``."""
     algorithm, params = key
     entry = _ALGOS[algorithm]
     if not warm_start or entry.warm is None or prev is None:
@@ -1084,6 +1107,9 @@ def _advance(
 
     def freeze(plan, edges, lo, hi, capacity, results, advance, n_solved,
                warm_applied, rounds, n_unique=0, last_schedule=None):
+        # rows that ran a fixpoint in this request, after dedup (a host
+        # value: no device sync)
+        telemetry.count("serve.rows_solved", n_unique)
         return SweepState(
             group_keys=tuple(k for k, _, _ in groups),
             group_sources=tuple(tuple(s) for _, s, _ in groups),
@@ -1092,7 +1118,7 @@ def _advance(
             capacity=capacity, results=results, graph_ref=g.src,
             last_advance=advance, n_solved=n_solved,
             warm_applied=warm_applied,
-            last_rounds=rounds[0] if len(rounds) == 1 else rounds,
+            last_rounds=tuple(rounds),
             mesh=mesh, n_solved_unique=n_unique, group_caps=caps,
             last_schedule=last_schedule,
         )
@@ -1198,6 +1224,7 @@ def _advance(
             )
         )
         if identical:
+            telemetry.count("serve.rows_solved", 0)
             return state.results, dataclasses.replace(
                 state, last_advance="noop", n_solved=0, warm_applied=False,
                 n_solved_unique=0)
@@ -1436,9 +1463,10 @@ def _advance(
         (schedule, prev_results, new_windows, new_sources, inits,
          maps_t, any_warm, n_unique) = built()
         _note(f"fused:{p.method}{shard_tag}")
-        # delta rung floored at C/8: at most four delta variants per
-        # capacity ever compile, pinning the fused cache over long horizons
-        delta_budget = min(max(rung(max(shift, 1)), C // 8), C)
+        # the entering positions scatter C/8 at a time, in as many steps
+        # as the slide needs: ONE fused variant per capacity, however far
+        # a batch of per-row windows jitters from advance to advance
+        delta_budget = max(C // 8, 1)
         telemetry.end("serve.plan")
         with telemetry.span("serve.dispatch"):
             results, edges, rounds = _call_donating(
@@ -1742,16 +1770,16 @@ def sweep_incremental(
     state is NOT consumed) for a cold start; pass the returned state back
     on the next advance.
 
-    A steady-state advance (forward slide within the ring's capacity and
-    delta rung) is ONE jitted dispatch (DESIGN.md §7.3).  Index AND hybrid
+    A steady-state advance (forward slide within the ring's capacity) is
+    ONE jitted dispatch (DESIGN.md §7.3).  Index AND hybrid
     plans delta-advance; scan plans reuse the full view untouched.
 
     ``warm_start=True`` explicitly opts into containment warm starts:
     EXACT for the default label-correcting EA (monotone min fixpoint) and
     for cc (hash-min labels), sound-but-not-bit-stable for reachability,
     and REFUSED (cold init, with ``state.warm_applied == False``) for
-    pagerank, bfs, kcore, betweenness and for EA under ``visit_once`` —
-    the unsound cases of DESIGN.md §7.2/§7.4.
+    latest departure, pagerank, bfs, kcore, betweenness and for EA under
+    ``visit_once`` — DESIGN.md §7.2/§7.4.
 
     ``ladder`` (DESIGN.md §7.9) sets the frontier-rung cap on the sweep's
     plan: cold solves run through the sparse frontier ladder

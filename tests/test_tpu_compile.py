@@ -124,3 +124,36 @@ def test_segment_combine_windows_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def test_journeys_fused_step_fits_one_chip(one_chip):
+    """The fused advance of a journey planner at London's scale (4.85M
+    connections, 20,843 stops, a 2^19-slot ring): the delta scatter in
+    C/8 chunks, then a group of four earliest-arrival rows and one of four
+    latest-departure rows, each over the ring."""
+    from repro.core.edgemap import EdgeView
+    from repro.engine.plan import make_plan
+    from repro.serve import window_sweep as ws
+
+    e, v, c, q = 4_850_431, 20_843, 1 << 19, 4
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+    fields = (i32((e,)),) * 4 + (_spec((e,), jnp.float32, one_chip),)
+    plan = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        make_plan("index", "xla_segment", budget=c, ring_capacity=c,
+                  n_windows=2 * q))
+    ring = EdgeView(*(i32((c,)),) * 4, _spec((c,), jnp.float32, one_chip),
+                    _spec((c,), jnp.bool_, one_chip))
+    schedule = tuple((alg, (), (0,) * q, tuple(range(q)), None)
+                     for alg in ("earliest_arrival", "latest_departure"))
+    compiled = ws._fused_step_ring.lower(
+        fields, i32((e,)), plan, ring, (i32((q, v)),) * 2,
+        (i32((q, 2)),) * 2, (i32((q,)),) * 2, (None, None), None, i32((3,)),
+        method="index", n_vertices=v, capacity=c, delta_budget=c // 8,
+        schedule=schedule).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+    text = compiled.as_text()
+    assert "fixpoint.latest_departure" in text, "the scope names no op"
